@@ -466,7 +466,7 @@ def _slab_knn_pallas(Vq, Vc, k, exclude_self, block_q=128):
 
     from jax.experimental import pallas as pl
 
-    from repro.core.knn import _acc_sq
+    from repro.core.knn import _acc_sq_cols
     from repro.kernels.knn_topk import knn_topk as ktk
 
     E_max, Lc = Vq.shape[0], Vc.shape[1]
@@ -483,18 +483,19 @@ def _slab_knn_pallas(Vq, Vc, k, exclude_self, block_q=128):
             invalid = invalid | (col_ids == row_ids)
         D = jnp.zeros((bq, Lc_pad), jnp.float32)
         for e in range(E_max):
-            D = _acc_sq(D, vq_ref[e, :], vc_ref[e, :], jnp.float32)
+            D = _acc_sq_cols(D, vq_ref[:, e : e + 1], vc_ref[e : e + 1, :],
+                             jnp.float32)
             Dm = jnp.where(invalid, ktk._BIG, D)
-            idxs, dists = ktk._kpass_select(Dm, col_ids, k, Lc_pad)
+            idxs, dists = ktk._kpass_select(Dm, 0, k)
             idx_ref[e] = idxs
             dist_ref[e] = dists
 
-    def call_split(Vq_p, row0, rows_pad, bq):
+    def call_split(VqT_p, row0, rows_pad, bq):
         return pl.pallas_call(
             functools.partial(kernel, bq=bq, row0=row0),
             grid=(rows_pad // bq,),
             in_specs=[
-                pl.BlockSpec((E_max, bq), lambda i: (0, i)),
+                pl.BlockSpec((bq, E_max), lambda i: (i, 0)),
                 pl.BlockSpec((E_max, Lc_pad), lambda i: (0, 0)),
             ],
             out_specs=[
@@ -506,7 +507,7 @@ def _slab_knn_pallas(Vq, Vc, k, exclude_self, block_q=128):
                 jax.ShapeDtypeStruct((E_max, rows_pad, k), jnp.float32),
             ],
             interpret=True,
-        )(Vq_p, Vc_p)
+        )(VqT_p, Vc_p)
 
     return ktk._over_query_splits(Vq, block_q, call_split)
 
@@ -1083,6 +1084,9 @@ def main() -> None:
             if stale.exists():
                 stale.unlink()
         (BENCH_DIR / "CHECK_summary.json").unlink(missing_ok=True)
+    from repro.runtime.platform import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name in names:
         BENCHES[name]()

@@ -46,16 +46,17 @@ from repro.core.stats import simplex_weights
 INF = np.float32(np.inf)
 
 # Ceiling of the per-program streaming working set the tile calibration
-# aims for: the 16 MB TPU VMEM size.  Wide tiles are the lever that
-# amortizes per-tile selection+merge dispatch overhead (measured: tile
-# 8192 beats 4096 by ~15% at Lc >= 16k); the KNN_TILE_MAX cap below is
-# what keeps the per-program footprint (~10 MB at the paper shape, see
-# stream_vmem_bytes) inside VMEM with double-buffer headroom.
+# aims for: the 16 MB TPU scoped-VMEM default.  Wide tiles amortize the
+# per-tile selection+merge overhead; the KNN_TILE_MAX cap below is what
+# keeps the kernels inside scoped VMEM.
 KNN_TILE_BUDGET_BYTES = 16 * 2**20
 # Lane-aligned bounds for calibrated candidate tiles: narrower than 128
-# wastes VPU lanes, wider than 8192 exceeds the VMEM budget at paper
-# shapes before it buys any more merge amortization.
-KNN_TILE_MIN, KNN_TILE_MAX = 128, 8192
+# wastes VPU lanes.  Wider than 4096 breaks the v5e compiler's 16 MB
+# scoped-VMEM limit: the Mosaic kernels hold about five tile-sized
+# 32-bit temporaries per 128 query rows (distances, masked keys,
+# knocked-out keys, column iota, selects) — the prefix kernel at 8192
+# wide asked for 19.3 MB and was refused.
+KNN_TILE_MIN, KNN_TILE_MAX = 128, 4096
 # Host (pure-jnp) streaming profile: the working set targets the CPU
 # last-level cache, not VMEM, and XLA:CPU's top_k carries a ~1.5 ms
 # fixed cost PER CALL (measured at 128 rows; two 8192-wide calls lose to
@@ -199,10 +200,10 @@ def merge_topk_sorted(run_i, run_d, new_i, new_d, k: int):
     (dist, id, rank) triples travel together through every exchange, so
     the output order is deterministic and partition-independent; padding
     sentinels order strictly after every real entry and can only surface
-    in the k > (real candidates) cases the builders reject.  Runs
-    unchanged inside the Pallas kernels (pure jnp ops on the VPU) and in
-    the jnp builders — one definition for the whole bit-identity
-    contract.
+    in the k > (real candidates) cases the builders reject.  The Pallas
+    kernels merge under the same total order with a lane-friendly
+    two-pointer form (kernels/knn_topk._merge_sorted), so both give the
+    same tables bit-for-bit.
     """
     K = _next_pow2(k)
 
@@ -285,7 +286,15 @@ def _acc_sq(D: jax.Array, vq: jax.Array, vc: jax.Array, dist_dtype) -> jax.Array
     work here: it is dropped before the fusion/codegen stage that decides
     contraction, and ``abs`` is folded by the algebraic simplifier.
     """
-    sq = jnp.square(vq[:, None] - vc[None, :]).astype(dist_dtype)
+    return _acc_sq_cols(D, vq[:, None], vc[None, :], dist_dtype)
+
+
+def _acc_sq_cols(D, q_col, c_row, dist_dtype):
+    """:func:`_acc_sq` on operands already shaped for broadcasting: a
+    (rows, 1) query column against a (1, cols) candidate row — the form
+    the Pallas kernels read straight from their VMEM blocks.  The one
+    definition of the float sequence."""
+    sq = jnp.square(q_col - c_row).astype(dist_dtype)
     return D + jnp.maximum(sq, jnp.zeros((), dist_dtype))
 
 

@@ -40,7 +40,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ccm, knn, simplex
@@ -75,7 +75,7 @@ def make_simplex_fn(mesh, cfg: EDMConfig):
             mesh=mesh,
             in_specs=(P(axes, None),),
             out_specs=(P(axes, None), P(axes)),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -94,7 +94,7 @@ def make_ccm_chunk_fn(mesh, cfg: EDMConfig):
             mesh=mesh,
             in_specs=(P(axes, None), P(None, None), P(None)),
             out_specs=P(axes, None),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -113,7 +113,7 @@ def make_ccm_chunk_fn_bucketed(mesh, cfg: EDMConfig, plan: "ccm.BucketPlan"):
             mesh=mesh,
             in_specs=(P(axes, None), P(None, None)),
             out_specs=P(axes, None),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -131,7 +131,7 @@ def make_ccm_tables_fn(mesh, cfg: EDMConfig):
             mesh=mesh,
             in_specs=(P(axes, None),),
             out_specs=(tspec, tspec),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -146,7 +146,7 @@ def make_ccm_tables_fn_bucketed(mesh, cfg: EDMConfig, plan: "ccm.BucketPlan"):
             mesh=mesh,
             in_specs=(P(axes, None),),
             out_specs=(tspec, tspec),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -167,7 +167,7 @@ def make_ccm_tile_fn(mesh, cfg: EDMConfig):
             mesh=mesh,
             in_specs=(tspec, tspec, P(None, None), P(None)),
             out_specs=P(axes, None),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -189,7 +189,7 @@ def make_ccm_tile_fn_bucketed(mesh, cfg: EDMConfig):
                 mesh=mesh,
                 in_specs=(tspec, tspec, P(None, None)),
                 out_specs=P(axes, None),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -228,7 +228,7 @@ def make_knn_shard_fn(mesh, cfg: EDMConfig, k: int, exclude_self: bool,
             mesh=mesh,
             in_specs=(P(None, None), P(None, axes), P(axes, None)),
             out_specs=(tspec, tspec),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -259,7 +259,7 @@ def make_knn_shard_merge_fn(mesh, cfg: EDMConfig, k: int, k_s: int,
             mesh=mesh,
             in_specs=(P(None, None), P(None, axes), P(axes, None)),
             out_specs=(rspec, rspec),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -15,7 +15,7 @@ through ``EDMConfig(engine="my-engine")``.
 """
 from __future__ import annotations
 
-from repro.engine.base import Engine, default_interpret
+from repro.engine.base import Engine
 from repro.engine.pallas import PallasEngine, PallasInterpretEngine
 from repro.engine.reference import ReferenceEngine
 
@@ -53,7 +53,6 @@ __all__ = [
     "PallasInterpretEngine",
     "ReferenceEngine",
     "available_engines",
-    "default_interpret",
     "get_engine",
     "register",
 ]

@@ -8,11 +8,10 @@ design point).  Concrete engines:
 
   * ``reference``        — pure jnp (core/knn.py); the oracle everything
                            else is checked against.
-  * ``pallas-interpret`` — Pallas kernels forced into interpret mode;
+  * ``pallas-interpret`` — Pallas kernels in the Pallas interpreter;
                            numerics of the TPU kernels, runs anywhere.
-  * ``pallas-compiled``  — Pallas kernels compiled natively on TPU and
-                           auto-falling back to interpret mode elsewhere
-                           (the old ``use_kernels=True`` behaviour).
+  * ``pallas-compiled``  — Pallas kernels compiled with Mosaic for the
+                           TPU; raises on any other backend.
 
 Engines are *stateless*; ops may be called inside jit/shard_map traces
 (engine resolution happens at trace time because ``EDMConfig`` is a
@@ -23,7 +22,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret
 
 
 class Engine:

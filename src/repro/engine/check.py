@@ -104,9 +104,14 @@ def check_engine(
 
 
 def main() -> None:  # pragma: no cover - CLI smoke entry
+    import jax
+
     from repro.engine import available_engines
 
     for name in available_engines():
+        if name == "pallas-compiled" and jax.default_backend() != "tpu":
+            print(name, "not run: compiles for a TPU only")
+            continue
         errs = check_engine(name)
         print(name, {k: f"{v:.2e}" for k, v in errs.items()})
 

@@ -1,10 +1,11 @@
 """Pallas-kernel engines (DESIGN.md SS5).
 
-``pallas-compiled`` compiles the kernels natively on TPU and transparently
-falls back to interpret mode elsewhere (exactly the old
-``EDMConfig.use_kernels=True`` routing); ``pallas-interpret`` pins
-interpret mode everywhere so the kernel numerics can be validated on any
-backend, including TPU hosts.
+``pallas-compiled`` compiles the kernels with Mosaic for the TPU and
+raises on any other backend — it never falls back to the interpreter, so
+a run that names it either runs on the chip or fails.
+``pallas-interpret`` runs the same kernels in the Pallas interpreter on
+any backend: the kernel numerics, validated without a chip (tests, CPU
+checks).  Interpret mode happens only when this engine is named.
 
 Both route kNN-table construction through the streaming kernels in
 kernels/knn_topk — including the in-kernel prefix-snapshot kernel for
@@ -14,7 +15,9 @@ kernels/ccm_lookup.
 """
 from __future__ import annotations
 
-from repro.engine.base import Engine, default_interpret
+import jax
+
+from repro.engine.base import Engine
 
 # knn_tables is entered at jit-trace time, so each distinct kernel shape
 # emits its VMEM working set exactly once per compile — dedupe beyond
@@ -41,13 +44,20 @@ def _emit_vmem(E_max: int, k: int, tile: int, cfg) -> None:
 
 
 class PallasEngine(Engine):
-    """interpret=None -> native on TPU, interpret elsewhere."""
+    """The kernels compiled for the TPU; raises on any other backend."""
 
     name = "pallas-compiled"
-    interpret: bool | None = None
 
     def _interpret(self) -> bool:
-        return default_interpret() if self.interpret is None else self.interpret
+        backend = jax.default_backend()
+        if backend != "tpu":
+            raise RuntimeError(
+                f"engine 'pallas-compiled' compiles its Pallas kernels for "
+                f"a TPU, but the JAX backend is {backend!r}; name engine "
+                f"'pallas-interpret' to run the kernels in the Pallas "
+                f"interpreter, or 'reference'"
+            )
+        return False
 
     def knn_tables(self, Vq, Vc, k, *, exclude_self, cfg):
         from repro.kernels.knn_topk.ops import knn_topk_streaming
@@ -86,5 +96,9 @@ class PallasEngine(Engine):
 
 
 class PallasInterpretEngine(PallasEngine):
+    """The same kernels in the Pallas interpreter, on any backend."""
+
     name = "pallas-interpret"
-    interpret: bool | None = True
+
+    def _interpret(self) -> bool:
+        return True

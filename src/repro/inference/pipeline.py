@@ -34,7 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import ccm
@@ -69,7 +69,7 @@ def make_conv_tables_fn(mesh, cfg: EDMConfig, plan, lib_sizes):
             mesh=mesh,
             in_specs=(P(axes, None), P(None)),
             out_specs=(tspec, tspec),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -95,7 +95,7 @@ def make_conv_tile_fn(mesh, cfg: EDMConfig):
                 mesh=mesh,
                 in_specs=(tspec, tspec, P(None, None)),
                 out_specs=P(None, axes, None),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -123,7 +123,7 @@ def make_null_tile_fn(mesh, cfg: EDMConfig, m: int):
                 mesh=mesh,
                 in_specs=(tspec, tspec, P(None, None), P(axes, None)),
                 out_specs=P(axes, None),
-                check_rep=False,
+                check_vma=False,
             )
         )
 

@@ -1,14 +1,8 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Shared helpers for the Pallas kernel wrappers."""
-from __future__ import annotations
+"""Pallas TPU kernels of the EDM hot path (DESIGN.md SS2/SS8): kNN-table
+selection (knn_topk) and the batched CCM lookup (ccm_lookup).
 
-import jax
-
-
-def default_interpret() -> bool:
-    """Pallas TPU kernels run natively on TPU; everywhere else (CPU CI,
-    this container) they are validated in interpret mode.  One definition
-    shared by every kernels/*/ops.py wrapper and the engine layer."""
-    return jax.default_backend() != "tpu"
+Every wrapper takes ``interpret`` explicitly: False compiles with Mosaic
+for the TPU, True runs the Pallas interpreter (tests and the
+``pallas-interpret`` engine).  Nothing here picks a mode from the
+backend it finds.
+"""

@@ -15,4 +15,7 @@ def ccm_lookup_ref(
     Returns (B, Lq).
     """
     g = Y_fut[:, idx]  # (B, Lq, k)
-    return jnp.einsum("tk,btk->bt", w, g)
+    # HIGHEST: on TPU a default-precision f32 einsum runs in bf16 passes.
+    return jnp.einsum(
+        "tk,btk->bt", w, g, precision=jax.lax.Precision.HIGHEST
+    )

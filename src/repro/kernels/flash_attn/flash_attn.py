@@ -73,7 +73,8 @@ def flash_attn_pallas(
     causal: bool,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """q: (BH, Sq, dh); k/v: (BK, Sk, dh) with BH = B*H, BK = B*K.
     Head grouping (GQA) is encoded in the k/v index maps: q head h reads

@@ -7,7 +7,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret as _default_interpret
 from repro.kernels.flash_attn.flash_attn import flash_attn_pallas
 
 
@@ -21,11 +20,11 @@ def flash_attn(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool | None = None,
+    *,
+    interpret: bool,
 ) -> jax.Array:
-    """q: (B, Sq, H, dh); k/v: (B, Sk, K, dh), H % K == 0 -> (B, Sq, H, dh)."""
-    if interpret is None:
-        interpret = _default_interpret()
+    """q: (B, Sq, H, dh); k/v: (B, Sk, K, dh), H % K == 0 -> (B, Sq, H, dh).
+    interpret: True runs the Pallas interpreter, False compiles for TPU."""
     B, Sq, H, dh = q.shape
     Sk, K = k.shape[1], k.shape[2]
     rep = H // K

@@ -7,16 +7,20 @@ The paper's hot spot (97% of cppEDM runtime) re-architected for TPU
 dimension.  Each program accumulates a (block_q, tile_c) distance tile
 on-chip from the lag slices, partial-sorts the tile to its own top-k
 with the k-pass selector, and folds it into a running SORTED
-(E_max, block_q, k) top-k carried in VMEM scratch across tiles via the
-shared bitonic partial merge network (core/knn.merge_topk_sorted) —
-O(k log k) per merge, independent of tile width.  Per-program VMEM is
-O(E_max*tile_c + block_q*tile_c + E_max*block_q*k) — INDEPENDENT of Lc
-(``stream_block_shapes`` is the pure shape function the CI guard asserts
-on): arbitrary library lengths fit a 16 MB VMEM budget, and a tile
-covering the whole library degenerates to one direct selection, so small
-libraries pay nothing for the tiling.  (The historical dense
-distance-matrix kernel is gone; ``benchmarks/run.py knn`` keeps a local
-copy as the A/B reference.)
+(E_max, block_q, k) top-k carried in VMEM scratch across tiles via a
+two-pointer merge under the same (distance, arrival) order as the
+shared bitonic network of the jnp builders (core/knn.merge_topk_sorted).
+Per-program VMEM is O(E_max*tile_c + block_q*tile_c + E_max*block_q*k)
+— INDEPENDENT of Lc (``stream_block_shapes`` is the pure shape function
+the CI guard asserts on): arbitrary library lengths fit a 16 MB VMEM
+budget, and a tile covering the whole library degenerates to one direct
+selection, so small libraries pay nothing for the tiling.
+
+TPU layout: queries ride the sublane axis ((rows, E) query blocks, so a
+lag column is a (rows, 1) vector) and candidates the lane axis
+((E, tile_c) blocks, tile_c a multiple of 128); every in-kernel write
+of a selected entry goes through a lane-iota mask, never a dynamic
+lane offset.
 
 ``knn_topk_prefix_kernel``: the same running merge with candidate tiles
 CLIPPED at library-size boundaries (DESIGN.md SS9): candidates are
@@ -32,7 +36,7 @@ argmin on the VPU (k = E+1 <= 21); TPU has no radix-sort analogue, and
 k-pass selection is O(k*width) vector work per row versus
 O(width log width) for a sort.  Candidate columns are padded to the lane
 boundary and masked with _BIG.  Tie rule: argmin picks the first minimum
-position, and the merge network's (distance, rank) key keeps running
+position, and the merge's (distance, arrival) order keeps running
 entries ahead of tile entries — equal distances always resolve to the
 earliest sweep position (the lowest candidate id in natural order),
 exactly the lax.top_k rule, so the kernels and the jnp builders agree
@@ -58,46 +62,60 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# THE shared pinned-rounding accumulate (maximum(sq, 0) FMA guard) and THE
-# shared partial merge network: one definition each for the jnp builders,
-# the kernels, and the ref oracle — the exact float/compare sequences the
-# cross-layout bit-identity contract rests on.
-from repro.core.knn import _next_pow2, _acc_sq, merge_topk_sorted
+# THE shared pinned-rounding accumulate (maximum(sq, 0) FMA guard): one
+# definition for the jnp builders, the kernels, and the ref oracle — the
+# exact float sequence the cross-layout bit-identity contract rests on.
+from repro.core.knn import _acc_sq_cols
 
 _BIG = 3.0e38  # finite +inf stand-in (avoids inf-inf NaNs)
 _IMAX = 2147483647  # python literal: a jnp scalar here would be captured
 # by pallas kernel traces as a constant, which pallas_call rejects.
+_LANES = 128  # TPU vreg lane width: minor block dims are multiples of it
+# Query blocks are independent; the candidate-tile axis carries the
+# running top-k (and the prefix kernel's revisited snapshot block).
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary")
+)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _query_splits(Lq: int, block_q: int) -> list[tuple[int, int, int]]:
     """Query-axis work plan: [(row0, rows, block)] — full ``block_q``
     blocks plus one 8-row-aligned tail block for the ragged remainder
     (sublane granularity), so padded tail rows cost at most 7 rows of
-    k-pass VPU work instead of a whole extra block."""
+    k-pass VPU work instead of a whole extra block.  Queries ride the
+    sublane axis of the kernels' (rows, E) query blocks, so an 8-aligned
+    tail block is a legal TPU block shape."""
     main = (Lq // block_q) * block_q
     splits = []
     if main:
         splits.append((0, main, block_q))
     rem = Lq - main
     if rem:
-        splits.append((main, rem, min(block_q, max(8, -(-rem // 8) * 8))))
+        splits.append((main, rem, min(block_q, max(8, _round_up(rem, 8)))))
     return splits
 
 
 def _over_query_splits(Vq, block_q, call_split, q_axis: int = 1):
-    """Shared wrapper scaffold: run ``call_split(Vq_p, row0, rows_pad,
-    bq)`` -> (idx, dist) over the _query_splits plan (padding each split
-    to a block multiple) and stitch the per-split results back along the
-    query axis (``q_axis`` of the OUTPUT arrays)."""
+    """Shared wrapper scaffold: run ``call_split(VqT_p, row0, rows_pad,
+    bq)`` -> (idx, dist) over the _query_splits plan and stitch the
+    per-split results back along the query axis (``q_axis`` of the OUTPUT
+    arrays).  Vq is (E, Lq); each split hands the kernel its queries
+    TRANSPOSED to (rows_pad, E), padded to a block multiple — queries on
+    sublanes, lag rows on lanes, so the kernel reads one lag column as a
+    (rows, 1) vector against a (1, tile_c) candidate row."""
     Lq = Vq.shape[1]
     take = (slice(None),) * q_axis
     outs = []
     for row0, rows, bq in _query_splits(Lq, block_q):
         rows_pad = pl.cdiv(rows, bq) * bq
-        Vq_p = jnp.pad(
-            Vq[:, row0 : row0 + rows], ((0, 0), (0, rows_pad - rows))
+        VqT_p = jnp.pad(
+            Vq[:, row0 : row0 + rows].T, ((0, rows_pad - rows), (0, 0))
         )
-        idx, dist = call_split(Vq_p, row0, rows_pad, bq)
+        idx, dist = call_split(VqT_p, row0, rows_pad, bq)
         outs.append((idx[take + (slice(0, rows),)],
                      dist[take + (slice(0, rows),)]))
     if len(outs) == 1:
@@ -108,38 +126,49 @@ def _over_query_splits(Vq, block_q, call_split, q_axis: int = 1):
     )
 
 
-def _kpass_select(md, mi, k, width):
+def _lane_tile(tile_c: int, k: int, width: int) -> int:
+    """Candidate-tile width legal on the TPU: a multiple of the 128-lane
+    vreg width, at least k (the per-tile partial sort needs k real
+    columns) and no wider than the lane-padded ``width``."""
+    return max(_round_up(k, _LANES), min(_round_up(tile_c, _LANES),
+                                         _round_up(width, _LANES)))
+
+
+def _kpass_select(md, mi, k):
     """Fused k-pass masked-argmin top-k over a (rows, width) buffer.
 
-    md: f32 merge keys; mi: i32 candidate ids per column, OR a scalar
-    BASE when the ids are affine in the column position (id = base +
-    column, the stream kernel's natural-order tiles) — the affine form
-    skips the full-width id-extraction gather (``base + argmin`` is a
-    per-row scalar add), about a fifth of the per-pass VPU work.
-    Selected positions are knocked out with +inf (strictly above the
-    _BIG mask value, so an already-taken position can never shadow a
-    real masked candidate).  Returns (ids, dists) each (rows, k), sorted
-    ascending with ties resolved to the earliest buffer position —
-    identical for both id forms (argmin picks exactly one position, so
-    the gathered id IS base + argmin).
+    md: f32 merge keys; mi: i32 candidate ids per column (a (1, width)
+    row broadcast over the rows), OR a scalar BASE when the ids are
+    affine in the column position (id = base + column, the stream
+    kernel's natural-order tiles) — the affine form skips the full-width
+    id-extraction pass (``base + argmin`` is a per-row add).  The argmin
+    is the first column holding the row minimum (min over the column
+    iota where the key equals the minimum).  Selected positions are
+    knocked out with +inf (strictly above the _BIG mask value, so an
+    already-taken position can never shadow a real masked candidate),
+    and pass ``kk`` writes its pick into output lane ``kk`` through a
+    lane-iota mask — no dynamic-offset store, which Mosaic cannot lower.
+    Returns (ids, dists) each (rows, k), sorted ascending with ties
+    resolved to the earliest buffer position — identical for both id
+    forms.
     """
-    rows = md.shape[0]
+    rows, width = md.shape
     pos = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
     affine = jnp.ndim(mi) == 0
 
     def body(kk, carry):
         md_cur, idxs, dists = carry
-        m = jnp.min(md_cur, axis=1)
-        am = jnp.argmin(md_cur, axis=1).astype(jnp.int32)
-        hit = pos == am[:, None]
+        m = jnp.min(md_cur, axis=1, keepdims=True)
+        am = jnp.min(jnp.where(md_cur == m, pos, _IMAX), axis=1, keepdims=True)
+        hit = pos == am
         if affine:
             sel = mi + am
         else:
-            sel = jnp.min(
-                jnp.where(hit, mi, jnp.full((), _IMAX, jnp.int32)), axis=1
-            )
-        idxs = jax.lax.dynamic_update_index_in_dim(idxs, sel, kk, axis=1)
-        dists = jax.lax.dynamic_update_index_in_dim(dists, m, kk, axis=1)
+            sel = jnp.min(jnp.where(hit, mi, _IMAX), axis=1, keepdims=True)
+        here = slot == kk
+        idxs = jnp.where(here, sel, idxs)
+        dists = jnp.where(here, m, dists)
         md_cur = jnp.where(hit, jnp.float32(jnp.inf), md_cur)
         return md_cur, idxs, dists
 
@@ -156,6 +185,56 @@ def _kpass_select(md, mi, k, width):
     return idxs, dists
 
 
+def _merge_sorted(run_i, run_d, new_i, new_d, k):
+    """Two-pointer merge of two sorted (rows, k) top-k lists -> the top-k
+    of their union, (rows, k) ascending.
+
+    The in-kernel form of core/knn.merge_topk_sorted: the same total
+    order — (distance, arrival) with every running entry arriving before
+    every tile entry, so ties go to the running list and, within a list,
+    to the earlier position — hence the same output bit-for-bit, but
+    built from lane-mask selects and lane reductions only (the network's
+    reshapes and reversals along the lane axis do not lower in Mosaic).
+    Step kk reads the head of each list through a lane-iota mask, takes
+    the smaller (running on ties; an exhausted list never wins) and
+    writes it to output lane kk.  O(k^2) lane work per row — small next
+    to the k-pass tile selection it follows.
+    """
+    rows = run_d.shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+
+    def head(a, p, fill):
+        return jnp.min(jnp.where(slot == p, a, fill), axis=1, keepdims=True)
+
+    def body(kk, carry):
+        pr, pn, oi, od = carry
+        rd, nd = head(run_d, pr, jnp.inf), head(new_d, pn, jnp.inf)
+        ri, ni = head(run_i, pr, _IMAX), head(new_i, pn, _IMAX)
+        take = (pr < k) & ((pn >= k) | (rd <= nd))
+        here = slot == kk
+        oi = jnp.where(here, jnp.where(take, ri, ni), oi)
+        od = jnp.where(here, jnp.where(take, rd, nd), od)
+        step = take.astype(jnp.int32)
+        return pr + step, pn + (1 - step), oi, od
+
+    zero = jnp.zeros((rows, 1), jnp.int32)
+    _, _, oi, od = jax.lax.fori_loop(
+        0, k, body,
+        (zero, zero, jnp.zeros((rows, k), jnp.int32),
+         jnp.zeros((rows, k), jnp.float32)),
+    )
+    return oi, od
+
+
+def _restore_inf(d):
+    """Masked candidates carry the finite _BIG inside the selection (the
+    k-pass knockout needs +inf strictly above the mask value); the dense
+    oracle reports them as +inf, so restore inf on the way out — only
+    reachable in the degenerate k == Lc case where a masked self is
+    selected."""
+    return jnp.where(d >= _BIG, jnp.float32(jnp.inf), d)
+
+
 # ------------------------------------------------------------- streaming
 def stream_block_shapes(
     E_max: int, k: int, block_q: int, tile_c: int
@@ -164,24 +243,25 @@ def stream_block_shapes(
 
     A PURE function of (E_max, k, block_q, tile_c): the library length Lc
     appears nowhere — it only scales the GRID — which is the flat-VMEM
-    scaling guarantee the CI guard test asserts (tests/test_knn_streaming).
+    scaling guarantee the CI guard asserts (tests/test_knn_streaming).
     ``knn_topk_stream_pallas`` builds its BlockSpecs and scratch from this
-    dict, so the guard constrains the real kernel, not a copy.
+    dict, so the guard constrains the real kernel, not a copy.  Queries
+    arrive transposed, (block_q, E_max): query rows on sublanes.
 
     ``tile_ids``/``tile_topk``/``merge`` are kernel-internal working
     arrays (the candidate-id lanes, the tile's own partial top-k, and the
-    DOUBLED (2 * next_pow2(k)) merge-network buffers), tracked here so
+    merged (id, dist) output of the two-pointer merge), tracked here so
     ``stream_vmem_bytes`` models the true peak.
     """
     return {
-        "vq": (E_max, block_q),
+        "vq": (block_q, E_max),
         "vc_tile": (E_max, tile_c),
         "out": (E_max, block_q, k),
         "scratch_idx": (E_max, block_q, k),
         "scratch_dist": (E_max, block_q, k),
         "tile_ids": (block_q, tile_c),
         "tile_topk": (block_q, k),
-        "merge": (block_q, 2 * _next_pow2(k)),
+        "merge": (block_q, k),
     }
 
 
@@ -190,9 +270,7 @@ def stream_vmem_bytes(
 ) -> int:
     """VMEM budget estimate for one streaming program (DESIGN.md SS8):
     blocks + scratch + the distance tile (dist_dtype) + the candidate-id
-    lanes + the tile partial top-k + the merge network's doubled
-    (dist f32, id i32, rank i32) working triples — the top-k scratch
-    doubling the pre-merge-network model used to omit.  Independent of
+    lanes + the tile partial top-k + the merge output.  Independent of
     Lc."""
     s = stream_block_shapes(E_max, k, block_q, tile_c)
     n = lambda shp: functools.reduce(lambda a, b: a * b, shp, 1)
@@ -204,7 +282,7 @@ def stream_vmem_bytes(
         + it * block_q * tile_c  # distance tile accumulator
         + 4 * n(s["tile_ids"])  # i32 candidate-id lanes
         + (4 + 4) * n(s["tile_topk"])  # tile partial top-k (id + dist)
-        + (4 + 4 + 4) * n(s["merge"])  # merge network (dist, id, rank)
+        + (4 + 4) * n(s["merge"])  # merged (id, dist)
     )
 
 
@@ -238,61 +316,57 @@ def knn_topk_stream_kernel(
     with ``single_tile`` statically true, the whole scratch/merge/flush
     machinery drops out of the program: the one-tile grid IS a direct
     dense selection, the small-library fast case the calibrator
-    exploits); every later tile folds in with the O(k log k) merge
-    network — running entries (globally earlier sweep positions, i.e.
-    smaller candidate ids) win ties via the network's rank key, so equal
-    distances resolve to the lowest candidate id, exactly the lax.top_k
-    rule: bit-identical to the jnp builders and the dense oracle.
+    exploits); every later tile folds in with the two-pointer merge —
+    running entries (globally earlier sweep positions, i.e. smaller
+    candidate ids) win ties, so equal distances resolve to the lowest
+    candidate id, exactly the lax.top_k rule: bit-identical to the jnp
+    builders and the dense oracle.
     """
     qi = pl.program_id(0)
     ci = pl.program_id(1)
 
     base = ci * tile_c
-    col_ids = base + jax.lax.broadcasted_iota(jnp.int32, (block_q, tile_c), 1)
-    invalid = col_ids >= Lc
+    col_ids = base + jax.lax.broadcasted_iota(jnp.int32, (1, tile_c), 1)
+    invalid = jnp.broadcast_to(col_ids >= Lc, (block_q, tile_c))
     if exclude_self:
         row_ids = row0 + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, tile_c), 0
+            jnp.int32, (block_q, 1), 0
         )
         invalid = invalid | (col_ids == row_ids)
 
-    def _restore_inf(d):
-        # Masked candidates carry the finite _BIG inside the selection
-        # (the k-pass knockout needs +inf strictly above the mask value);
-        # the dense oracle reports them as +inf, so restore inf on the
-        # way out — only reachable in the degenerate k == Lc case where
-        # a masked self is selected.
-        return jnp.where(d >= _BIG, jnp.float32(jnp.inf), d)
+    vq = vq_ref[...]  # (block_q, E_max)
+    lane = jax.lax.broadcasted_iota(jnp.int32, vq.shape, 1)
 
-    D = jnp.zeros((block_q, tile_c), dist_dtype)
-    t_is, t_ds = [], []
-    for e in range(E_max):  # static unroll: E_max <= 20
-        D = _acc_sq(D, vq_ref[e, :], vc_ref[e, :], dist_dtype)
+    def per_e(e, D):
+        # lag column e of the query block (a masked lane sum: exact) and
+        # lag row e of the candidate tile
+        q = jnp.sum(jnp.where(lane == e, vq, 0.0), axis=1, keepdims=True)
+        D = _acc_sq_cols(D, q, vc_ref[pl.ds(e, 1), :], dist_dtype)
         Dm = jnp.where(invalid, _BIG, D.astype(jnp.float32))
-        t_i, t_d = _kpass_select(Dm, base, k, tile_c)  # affine ids
+        t_i, t_d = _kpass_select(Dm, base, k)  # affine ids
         if single_tile:
             idx_ref[e] = t_i
             dist_ref[e] = _restore_inf(t_d)
-        else:
-            t_is.append(t_i)
-            t_ds.append(t_d)
-
-    if not single_tile:
-        # One batched (E_max, block_q, k) seed/fold per tile — the merge
-        # network broadcasts over leading dims, so folding every E at
-        # once costs one network instead of E_max of them.
-        T_i, T_d = jnp.stack(t_is), jnp.stack(t_ds)
+            return D
 
         @pl.when(ci == 0)
         def _seed():
-            idx_s[...] = T_i
-            dist_s[...] = T_d
+            idx_s[e] = t_i
+            dist_s[e] = t_d
 
         @pl.when(ci != 0)
         def _fold():
-            m_i, m_d = merge_topk_sorted(idx_s[...], dist_s[...], T_i, T_d, k)
-            idx_s[...] = m_i
-            dist_s[...] = m_d
+            m_i, m_d = _merge_sorted(idx_s[e], dist_s[e], t_i, t_d, k)
+            idx_s[e] = m_i
+            dist_s[e] = m_d
+
+        return D
+
+    jax.lax.fori_loop(
+        0, E_max, per_e, jnp.zeros((block_q, tile_c), dist_dtype)
+    )
+
+    if not single_tile:
 
         @pl.when(ci == pl.num_programs(1) - 1)
         def _flush():
@@ -305,33 +379,35 @@ def knn_topk_stream_pallas(
     Vc: jax.Array,
     k: int,
     exclude_self: bool,
+    *,
+    interpret: bool,
     block_q: int = 128,
     tile_c: int = 512,
-    interpret: bool = True,
     dist_dtype=jnp.float32,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw streaming pallas_call wrapper (padding via ops.knn_topk_streaming).
 
     VMEM per program is stream_vmem_bytes(...) — flat in Lc — so library
     length is bounded by HBM, not by the 16 MB VMEM budget.  tile_c is
-    clamped up to an 8-aligned width >= k (the per-tile partial sort
-    needs k real columns available) and down to the padded library width
-    (a tile covering Lc is one direct selection — the small-library fast
-    case the calibrator exploits).
+    made lane-legal by ``_lane_tile`` (a multiple of 128, >= k, no wider
+    than the padded library — a tile covering Lc is one direct selection,
+    the small-library fast case the calibrator exploits).  ``interpret``
+    is explicit: True runs the Pallas interpreter (tests, the
+    ``pallas-interpret`` engine), False compiles with Mosaic for the TPU.
     """
     E_max = Vq.shape[0]
     Lc = Vc.shape[1]
     if k > Lc:
         raise ValueError(f"k={k} exceeds candidate count Lc={Lc}")
-    tile_c = max(-(-k // 8) * 8, min(tile_c, pl.cdiv(Lc, 8) * 8))
+    tile_c = _lane_tile(tile_c, k, Lc)
     n_c = pl.cdiv(Lc, tile_c)
-    # Balance tile widths under the cap (same tile count, 8-aligned
-    # ceil(Lc / n_c) width) so the grid pays O(8 * n_c) padded columns
+    # Balance tile widths under the cap (same tile count, lane-aligned
+    # ceil(Lc / n_c) width) so the grid pays O(128 * n_c) padded columns
     # instead of a whole ragged tail tile.
-    tile_c = max(-(-k // 8) * 8, pl.cdiv(pl.cdiv(Lc, n_c), 8) * 8)
+    tile_c = _lane_tile(pl.cdiv(Lc, n_c), k, Lc)
     Vc_p = jnp.pad(Vc, ((0, 0), (0, n_c * tile_c - Lc)))
 
-    def call_split(Vq_p, row0, rows_pad, bq):
+    def call_split(VqT_p, row0, rows_pad, bq):
         shapes = stream_block_shapes(E_max, k, bq, tile_c)
         kernel = functools.partial(
             knn_topk_stream_kernel,
@@ -355,7 +431,7 @@ def knn_topk_stream_pallas(
             kernel,
             grid=(rows_pad // bq, n_c),
             in_specs=[
-                pl.BlockSpec(shapes["vq"], lambda i, j: (0, i)),
+                pl.BlockSpec(shapes["vq"], lambda i, j: (i, 0)),
                 pl.BlockSpec(shapes["vc_tile"], lambda i, j: (0, j)),
             ],
             out_specs=[
@@ -367,8 +443,9 @@ def knn_topk_stream_pallas(
                 jax.ShapeDtypeStruct((E_max, rows_pad, k), jnp.float32),
             ],
             scratch_shapes=scratch,
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
-        )(Vq_p, Vc_p)
+        )(VqT_p, Vc_p)
 
     return _over_query_splits(Vq, block_q, call_split)
 
@@ -382,16 +459,18 @@ def prefix_block_shapes(
     parameters: neither the library length nor the NUMBER of library
     sizes appears (the size count S only scales the output allocation
     and the grid's boundary-tile count), so prefix snapshots inherit the
-    flat-VMEM guarantee."""
+    flat-VMEM guarantee.  The candidate ids are one (1, tile_c) lane row
+    of a (1, n_tiles * tile_c) array — a block whose sublane dim equals
+    the array's, as the TPU tiling rule requires."""
     return {
-        "vq": (E_hi, block_q),
+        "vq": (block_q, E_hi),
         "vc_tile": (E_hi, tile_c),
         "ids": (1, tile_c),
         "out": (1, nb, block_q, k),
         "scratch_idx": (nb, block_q, k),
         "scratch_dist": (nb, block_q, k),
         "tile_topk": (block_q, k),
-        "merge": (block_q, 2 * _next_pow2(k)),
+        "merge": (block_q, k),
     }
 
 
@@ -439,39 +518,47 @@ def knn_topk_prefix_kernel(
         idx_s[...] = jnp.zeros(idx_s.shape, jnp.int32)
         dist_s[...] = jnp.full(dist_s.shape, jnp.inf, jnp.float32)
 
-    ids = jnp.broadcast_to(ids_ref[...], (block_q, tile_c))
-    invalid = ids < 0
+    ids = ids_ref[...]  # (1, tile_c)
+    invalid = jnp.broadcast_to(ids < 0, (block_q, tile_c))
     if exclude_self:
         row_ids = row0 + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, tile_c), 0
+            jnp.int32, (block_q, 1), 0
         )
         invalid = invalid | (ids == row_ids)
 
-    want = set(buckets)
-    D = jnp.zeros((block_q, tile_c), dist_dtype)
-    t_is, t_ds = [], []
-    for e in range(buckets[-1]):  # static unroll: E <= 20
-        D = _acc_sq(D, vq_ref[e, :], vc_ref[e, :], dist_dtype)
-        if e + 1 not in want:
-            continue
-        Dm = jnp.where(invalid, _BIG, D.astype(jnp.float32))
-        t_i, t_d = _kpass_select(Dm, ids, k, tile_c)
-        t_is.append(t_i)
-        t_ds.append(t_d)
-    # One batched (nb, block_q, k) fold per tile (see the stream kernel):
-    # slot si merges with bucket si's tile selection; the first tile's
-    # merge against the inf-seeded scratch is an identity.
-    T_i, T_d = jnp.stack(t_is), jnp.stack(t_ds)
-    m_i, m_d = merge_topk_sorted(idx_s[...], dist_s[...], T_i, T_d, k)
-    idx_s[...] = m_i
-    dist_s[...] = m_d
+    vq = vq_ref[...]  # (block_q, E_hi)
+    lane = jax.lax.broadcasted_iota(jnp.int32, vq.shape, 1)
 
-    idx_ref[0] = idx_s[...]
-    # Restore +inf on masked-selected entries (see the stream kernel's
-    # flush) so the carry matches the jnp builders bit-for-bit even in
-    # the degenerate k == prefix-size case.
-    d = dist_s[...]
-    dist_ref[0] = jnp.where(d >= _BIG, jnp.float32(jnp.inf), d)
+    def per_e(e, D):
+        q = jnp.sum(jnp.where(lane == e, vq, 0.0), axis=1, keepdims=True)
+        D = _acc_sq_cols(D, q, vc_ref[pl.ds(e, 1), :], dist_dtype)
+        # selection only at the bucket dimensions; bucket E's slot is the
+        # number of buckets below it
+        is_bucket = functools.reduce(
+            jnp.logical_or, [e + 1 == E for E in buckets]
+        )
+        si = sum(jnp.where(e + 1 > E, 1, 0) for E in buckets)
+
+        @pl.when(is_bucket)
+        def _select():
+            Dm = jnp.where(invalid, _BIG, D.astype(jnp.float32))
+            t_i, t_d = _kpass_select(Dm, ids, k)
+            # Slot si folds bucket si's tile selection; the first tile's
+            # merge against the inf-seeded scratch is an identity.
+            m_i, m_d = _merge_sorted(idx_s[si], dist_s[si], t_i, t_d, k)
+            idx_s[si] = m_i
+            dist_s[si] = m_d
+            idx_ref[0, si] = m_i
+            # Restore +inf on masked-selected entries (see the stream
+            # kernel's flush) so the carry matches the jnp builders
+            # bit-for-bit even in the degenerate k == prefix-size case.
+            dist_ref[0, si] = _restore_inf(m_d)
+
+        return D
+
+    jax.lax.fori_loop(
+        0, buckets[-1], per_e, jnp.zeros((block_q, tile_c), dist_dtype)
+    )
 
 
 def knn_topk_prefix_pallas(
@@ -481,9 +568,10 @@ def knn_topk_prefix_pallas(
     exclude_self: bool,
     buckets: tuple[int, ...],
     lib_sizes: tuple[int, ...],
+    *,
+    interpret: bool,
     block_q: int = 128,
     tile_c: int = 512,
-    interpret: bool = True,
     dist_dtype=jnp.float32,
     col_ids: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -513,7 +601,7 @@ def knn_topk_prefix_pallas(
     nb = len(buckets)
     S = len(lib_sizes)
     need = k + 1 if exclude_self else k
-    tile_c = -(-max(tile_c, need) // 8) * 8
+    tile_c = _round_up(max(tile_c, need), _LANES)
     bounds = core_knn._prefix_tile_bounds(lib_sizes, tile_c)
     n_tiles = len(bounds)
 
@@ -525,18 +613,18 @@ def knn_topk_prefix_pallas(
         pos[t, :w] = np.arange(start, stop, dtype=np.int32)
         valid[t, :w] = True
         slots[t] = bisect.bisect_left(lib_sizes, stop)
-    posj = jnp.asarray(pos)
-    validj = jnp.asarray(valid)
+    posj = jnp.asarray(pos.reshape(1, -1))
+    validj = jnp.asarray(valid.reshape(1, -1))
     if col_ids is None:
         ids_val = posj
     else:
         ids_val = jnp.take(col_ids.astype(jnp.int32), posj)
-    ids = jnp.where(validj, ids_val, -1)
+    ids = jnp.where(validj, ids_val, -1)  # (1, n_tiles * tile_c)
     gather = jnp.where(validj, ids_val, 0).reshape(-1)
     Vc_g = jnp.take(Vc[:E_hi], gather, axis=1)  # (E_hi, n_tiles * tile_c)
     slot_arr = jnp.asarray(slots)
 
-    def call_split(Vq_p, row0, rows_pad, bq):
+    def call_split(VqT_p, row0, rows_pad, bq):
         shapes = prefix_block_shapes(E_hi, nb, k, bq, tile_c)
         kernel = functools.partial(
             knn_topk_prefix_kernel,
@@ -557,11 +645,11 @@ def knn_topk_prefix_pallas(
                 num_scalar_prefetch=1,
                 grid=(rows_pad // bq, n_tiles),
                 in_specs=[
-                    pl.BlockSpec(shapes["vq"], lambda i, j, slots: (0, i)),
+                    pl.BlockSpec(shapes["vq"], lambda i, j, slots: (i, 0)),
                     pl.BlockSpec(
                         shapes["vc_tile"], lambda i, j, slots: (0, j)
                     ),
-                    pl.BlockSpec(shapes["ids"], lambda i, j, slots: (j, 0)),
+                    pl.BlockSpec(shapes["ids"], lambda i, j, slots: (0, j)),
                 ],
                 out_specs=[out_spec, out_spec],
                 scratch_shapes=[
@@ -573,7 +661,8 @@ def knn_topk_prefix_pallas(
                 jax.ShapeDtypeStruct((S, nb, rows_pad, k), jnp.int32),
                 jax.ShapeDtypeStruct((S, nb, rows_pad, k), jnp.float32),
             ],
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
-        )(slot_arr, Vq_p, Vc_g, ids)
+        )(slot_arr, VqT_p, Vc_g, ids)
 
     return _over_query_splits(Vq[:E_hi], block_q, call_split, q_axis=2)

@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret as _default_interpret
 from repro.kernels.knn_topk.knn_topk import (
     knn_topk_prefix_pallas,
     knn_topk_stream_pallas,
@@ -27,7 +26,8 @@ def knn_topk_streaming(
     block_q: int = 128,
     tile_c: int = 512,
     dist_dtype: str = "float32",
-    interpret: bool | None = None,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Multi-E kNN tables, STREAMING layout (DESIGN.md SS8).
 
@@ -41,11 +41,10 @@ def knn_topk_streaming(
     dist_dtype: distance-accumulator dtype (EDMConfig.dist_dtype;
     bfloat16 halves the tile working set, merge keys stay float32).
     Bit-identical to the dense jnp oracle (ref.knn_topk_ref).
+    interpret: True runs the Pallas interpreter, False compiles for TPU.
     """
     if exclude_self and Vq.shape != Vc.shape:
         raise ValueError("exclude_self requires query set == candidate set")
-    if interpret is None:
-        interpret = _default_interpret()
     return knn_topk_stream_pallas(
         Vq, Vc, k, exclude_self, block_q=block_q, tile_c=tile_c,
         interpret=interpret, dist_dtype=jnp.dtype(dist_dtype),
@@ -69,8 +68,9 @@ def knn_topk_prefix(
     block_q: int = 128,
     tile_c: int = 512,
     dist_dtype: str = "float32",
-    interpret: bool | None = None,
     col_ids: jax.Array | None = None,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """In-kernel prefix-snapshot kNN tables (DESIGN.md SS9).
 
@@ -81,11 +81,10 @@ def knn_topk_prefix(
     clipped at library-size boundaries and the running carry emitted at
     each boundary — ONE sweep over the largest library, bit-identical to
     core/knn.knn_tables_prefix_streaming and the per-size rebuild oracle.
+    interpret: True runs the Pallas interpreter, False compiles for TPU.
     """
     if exclude_self and Vq.shape != Vc.shape:
         raise ValueError("exclude_self requires query set == candidate set")
-    if interpret is None:
-        interpret = _default_interpret()
     return knn_topk_prefix_pallas(
         Vq, Vc, k, exclude_self, tuple(buckets), tuple(lib_sizes),
         block_q=block_q, tile_c=tile_c, interpret=interpret,

@@ -72,9 +72,10 @@ def init_fleet(
 ) -> dict:
     """Write the shared fleet spec every worker derives its queue from.
 
-    unit_rows=0 resolves to one local-mesh chunk (devices x lib_block) —
-    the natural claim granularity.  The spec pins dataset path, configs,
-    and the unit grid so W workers agree on the queue with no exchange.
+    unit_rows=0 resolves to one local-mesh chunk (devices x lib_block,
+    devices counted by a probe child) — the natural claim granularity.
+    The spec pins dataset path, configs, and the unit grid so W workers
+    agree on the queue with no exchange.
 
     ``platform`` / ``distributed`` are the multi-host opt-in (DESIGN.md
     SS14): workers apply the named runtime/platform.py tier before their
@@ -88,9 +89,11 @@ def init_fleet(
     meta = json.loads((pathlib.Path(dataset) / "meta.json").read_text())
     N, L = (int(s) for s in meta["shape"][:2])
     if unit_rows <= 0:
-        import jax
+        # A probe child counts the devices: this process stays off the
+        # backend, so the chips are free for the workers it spawns.
+        from repro.runtime.platform import probe_devices
 
-        unit_rows = len(jax.devices()) * cfg.lib_block
+        unit_rows = probe_devices(platform)[1] * cfg.lib_block
     if seed is None:
         seed = 0 if sig is None else sig.seed
     # Run fingerprint: dataset CONTENT (not path — the same path can hold
@@ -150,15 +153,14 @@ def spawn_worker(
 ) -> subprocess.Popen:
     """Spawn one fleet worker as a detached subprocess.
 
-    Workers share a JAX persistent compilation cache under the store
-    (unless the caller already exported one): W processes compile the
-    same jit signatures, so all but the first hit the disk cache —
-    the fleet's answer to the paper's GPU-init straggler tail (SSIV-B2).
+    Workers share the JAX persistent compilation cache that
+    runtime/platform.enable_compile_cache places — $JAX_COMPILATION_CACHE_DIR
+    when exported, else the one fixed directory in the checkout, so the
+    cache survives across runs and --out dirs: W processes compile the
+    same jit signatures, so all but the first hit the disk cache — the
+    fleet's answer to the paper's GPU-init straggler tail (SSIV-B2).
     """
     e = dict(os.environ if env is None else env)
-    e.setdefault("JAX_COMPILATION_CACHE_DIR",
-                 str(pathlib.Path(out_dir).resolve() / "jax_cache"))
-    e.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     # A locally-spawned worker must NOT inherit the driver's multi-host
     # rank: W children all claiming the driver's EDM_PROCESS_ID would
     # deadlock jax.distributed.initialize.  Cross-host workers are
@@ -865,6 +867,9 @@ def main(argv=None) -> None:
     # Platform tier + optional multi-host mesh join from the shared spec,
     # BEFORE the first jax touch below (DESIGN.md SS14).
     apply_spec_platform(args.out)
+    from repro.runtime import platform as rt_platform
+
+    rt_platform.enable_compile_cache()
     telemetry.configure_from_env(
         default_path=telemetry.worker_jsonl(args.out, args.worker_id),
         worker=args.worker_id,

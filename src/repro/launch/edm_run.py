@@ -51,11 +51,13 @@ from repro.inference import SignificanceConfig, run_significance
 from repro.runtime import autotune, history, platform, telemetry
 
 
-def _run_fleet(args, ts, cfg, sig):
+def _run_fleet(args, ts, cfg, sig, plat, n_dev):
     """--workers N: self-spawn a local masterless fleet (DESIGN.md SS10).
 
     The driver only prepares the shared store (dataset + fleet.json) and
-    spawns/waits on worker processes — it schedules nothing; workers
+    spawns/waits on worker processes, and never touches the jax backend
+    itself (``plat``/``n_dev`` come from a probe child), so every chip is
+    free for the workers — it schedules nothing; workers
     claim work units from the lease queue themselves.  A worker that
     dies is NOT fatal: the survivors reclaim its units after lease
     expiry, so the run completes as long as one worker lives (the
@@ -67,6 +69,15 @@ def _run_fleet(args, ts, cfg, sig):
 
     from repro.launch import edm_fleet
 
+    if plat == "tpu" and args.workers > 1:
+        # Each worker process takes every chip it sees, so a second local
+        # worker would wait on chips the first one holds.
+        raise SystemExit(
+            f"--workers {args.workers} on a TPU host with {n_dev} chip(s): "
+            "a worker process holds every local chip (its mesh spans all "
+            f"{n_dev}), so local workers beyond the first would wait on a "
+            "held chip; use --workers 1, or --workers 0 to run in-process"
+        )
     out = pathlib.Path(args.out)
     dataset = args.dataset
     if args.synthetic:
@@ -86,7 +97,8 @@ def _run_fleet(args, ts, cfg, sig):
         else:
             store.save_dataset(dataset, ts, {"synthetic": args.synthetic})
     edm_fleet.init_fleet(
-        out, dataset, cfg, sig, unit_rows=args.unit_rows, seed=args.seed,
+        out, dataset, cfg, sig,
+        unit_rows=args.unit_rows or n_dev * cfg.lib_block, seed=args.seed,
         # Fleet workers re-apply the driver's platform tier from
         # fleet.json; `distributed` opts externally-launched workers into
         # the multi-host mesh via their OWN rank env (DESIGN.md SS14) —
@@ -301,7 +313,8 @@ def build_parser() -> argparse.ArgumentParser:
         help="self-spawn a local fleet of this many masterless worker "
         "processes over the output store (DESIGN.md SS10); 0 = run "
         "in-process.  Any W produces bit-identical causal_map/rho_conv/"
-        "pvals arrays; workers share a JAX compilation cache under --out",
+        "pvals arrays.  The driver stays off the jax backend; on a TPU "
+        "host W is at most 1 (a worker holds every local chip)",
     )
     ap.add_argument(
         "--unit-rows", type=int, default=0,
@@ -346,7 +359,7 @@ def main():
     args = ap.parse_args()
 
     # Platform tier + multi-host mesh join, BEFORE any jax backend touch
-    # (XLA flags and jax_platform_name are latched at backend init).
+    # (XLA flags and jax_platforms are latched at backend init).
     if args.platform:
         applied = platform.apply_platform(args.platform)
         print(f"platform: tier {applied['tier']} "
@@ -381,6 +394,15 @@ def main():
         stream_depth=args.stream_depth, target_tile=args.target_tile,
         knn_tile_c=args.knn_tile,
     )
+    if args.workers > 0:
+        # The fleet driver stays off the jax backend (its workers need the
+        # chips): a probe child reports the platform and device count.
+        plat, n_dev = platform.probe_devices(args.platform)
+    else:
+        import jax
+
+        platform.enable_compile_cache()
+        plat, n_dev = jax.default_backend(), len(jax.devices())
     if not args.no_telemetry:
         telemetry.configure_from_env(
             default_path=telemetry.worker_jsonl(args.out, "main"),
@@ -394,9 +416,7 @@ def main():
         src = args.tune_from or args.out
         tuned = autotune.load_tuned(src) or autotune.recommend(src)
         if tuned is not None:
-            import jax
-
-            cfg = autotune.apply_to_cfg(cfg, tuned, len(jax.devices()))
+            cfg = autotune.apply_to_cfg(cfg, tuned, n_dev)
             rec = tuned["recommend"]
             # Schedule knobs (DESIGN.md SS13): the tuned lease TTL is
             # applied to the workers this driver spawns; the worker
@@ -435,7 +455,7 @@ def main():
         )
     if args.workers > 0:
         try:
-            _run_fleet(args, ts, cfg, sig)
+            _run_fleet(args, ts, cfg, sig, plat, n_dev)
             # Refresh the run-history record the finalize claimer wrote
             # so it also covers the driver's own telemetry tail (same
             # run identity -> replaces, never duplicates).

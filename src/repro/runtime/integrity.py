@@ -341,8 +341,7 @@ def _scan_assembled(d: pathlib.Path) -> Optional[dict]:
 
 
 def _tmp_residue(out: pathlib.Path) -> list[pathlib.Path]:
-    return [p for p in out.rglob("*.tmp-*")
-            if "jax_cache" not in p.parts and p.is_file()]
+    return [p for p in out.rglob("*.tmp-*") if p.is_file()]
 
 
 def fsck_store(

@@ -21,6 +21,11 @@ BEFORE the first jax backend touch:
   * :func:`spoof_cpu_devices` — the CI/dev lever: N virtual CPU devices
     in one process (XLA host-platform device-count spoof) so multi-shard
     collectives run anywhere.
+  * :func:`probe_devices` — platform and local device count as a child
+    process sees them, so a fleet driver can size its fleet while it
+    stays off the backend itself (one process per chip).
+  * :func:`enable_compile_cache` — THE persistent-compilation-cache
+    placement every entry point calls before its first compile.
 
 Everything here is wall-clock/topology only — byte-invisible to outputs
 (the bit-identity contracts of SS8/SS14 hold on every tier).
@@ -28,6 +33,9 @@ Everything here is wall-clock/topology only — byte-invisible to outputs
 from __future__ import annotations
 
 import os
+import pathlib
+import subprocess
+import sys
 import warnings
 from dataclasses import dataclass, field
 
@@ -47,7 +55,7 @@ class Tier:
     the jax backend initializes."""
 
     name: str
-    platform: str          # jax_platform_name
+    platform: str          # jax_platforms
     engine: str            # default repro.engine registry key
     x64: bool = False
     xla_flags: tuple[str, ...] = field(default_factory=tuple)
@@ -104,13 +112,10 @@ def default_engine(tier: str) -> str:
 
 def _backend_initialized() -> bool:
     """True once the jax runtime has instantiated a backend — after which
-    XLA_FLAGS / platform-name changes are silently ignored by jax."""
-    try:
-        from jax._src import xla_bridge
+    XLA_FLAGS / platform changes are silently ignored by jax."""
+    from jax._src import xla_bridge
 
-        return bool(xla_bridge._backends)
-    except Exception:  # pragma: no cover - private-API drift
-        return False
+    return xla_bridge.backends_are_initialized()
 
 
 def _merge_xla_flags(flags: tuple[str, ...]) -> str:
@@ -130,11 +135,12 @@ _APPLIED: dict | None = None
 def apply_platform(
     tier: str, *, x64: bool | None = None, cpu_devices: int | None = None
 ) -> dict:
-    """Apply a :data:`TIERS` entry: XLA_FLAGS env + jax.config platform
-    selection + x64 mode.  MUST run before the first jax backend touch
-    (device query, first op); a later call warns and changes nothing at
-    the runtime level.  Returns {tier, platform, engine, x64, xla_flags}
-    — the record edm_run stamps into telemetry.
+    """Apply a :data:`TIERS` entry: XLA_FLAGS env + ``jax_platforms``
+    (the one platform the backend may initialize) + x64 mode.  MUST run
+    before the first jax backend touch (device query, first op); a later
+    call warns and changes nothing at the runtime level.  Returns {tier,
+    platform, engine, x64, xla_flags} — the record edm_run stamps into
+    telemetry.
 
     ``cpu_devices`` (cpu tier only) spoofs N host devices for local
     multi-shard runs — the same knob CI's scale-smoke uses.
@@ -159,7 +165,7 @@ def apply_platform(
         else os.environ.get("XLA_FLAGS", "")
     import jax
 
-    jax.config.update("jax_platform_name", t.platform)
+    jax.config.update("jax_platforms", t.platform)
     use_x64 = t.x64 if x64 is None else x64
     jax.config.update(_X64_FLAG, use_x64)
     _APPLIED = {
@@ -185,6 +191,65 @@ def spoof_cpu_devices(n: int) -> None:
     if n < 1:
         raise ValueError(f"need at least one device, got {n}")
     _merge_xla_flags((f"--xla_force_host_platform_device_count={n}",))
+
+
+def probe_devices(tier: str | None = None, timeout: float = 300.0):
+    """(platform, local device count) as a fresh child process sees them.
+
+    A process that initializes the TPU backend holds every chip it sees
+    until it exits, so a driver that spawns workers must not touch the
+    backend itself: it asks a short-lived child instead, which exits —
+    releasing the chips — before any worker starts.  ``tier`` applies a
+    :data:`TIERS` platform to the child, as fleet workers do."""
+    env = dict(os.environ)
+    if tier is not None:
+        env["JAX_PLATFORMS"] = TIERS[tier].platform
+    code = ("import jax; "
+            "print(jax.default_backend(), jax.local_device_count())")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=timeout)
+    if r.returncode != 0:
+        raise RuntimeError(f"device probe failed: {r.stderr.strip()[-2000:]}")
+    plat, n = r.stdout.split()[-2:]
+    return plat, int(n)
+
+
+# ------------------------------------------------- compile-cache placement
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: The one in-checkout cache directory used when CACHE_ENV is unset (in
+#: .gitignore).  Fixed, because the path is part of what a later run
+#: must find again: a directory built from --out, a temporary name, a
+#: pid or the time never hits.
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def compile_cache_dir() -> pathlib.Path | None:
+    """The persistent compilation cache directory in force: the
+    environment's, else the one :func:`enable_compile_cache` placed, else
+    None (no cache)."""
+    d = os.environ.get(CACHE_ENV)
+    if d:
+        return pathlib.Path(d)
+    import jax
+
+    d = jax.config.jax_compilation_cache_dir
+    return pathlib.Path(d) if d else None
+
+
+def enable_compile_cache() -> pathlib.Path:
+    """Place JAX's persistent compilation cache; call before the first
+    compile.  With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads
+    it and nothing else is set here.  Otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`, caching every compile (no minimum compile
+    time), so a second run in the same checkout compiles less."""
+    d = os.environ.get(CACHE_ENV)
+    if d:
+        return pathlib.Path(d)
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return DEFAULT_CACHE_DIR
 
 
 # ------------------------------------------------------- multi-host mesh

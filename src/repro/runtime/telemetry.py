@@ -378,16 +378,21 @@ def iter_store_records(
 
 # ---------------------------------------------------- compile-cache probe
 def compile_cache_entries() -> int | None:
-    """Entry count of the JAX persistent compilation cache directory, or
-    None when no cache is configured.  Pipelines snapshot this at stage
-    boundaries: the DELTA is the number of fresh compilations the stage
-    paid (everything else was a cache hit — the fleet's straggler
-    metric, DESIGN.md SS10)."""
-    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not d:
+    """Entry count of the JAX persistent compilation cache directory
+    (runtime/platform.compile_cache_dir: the environment's, or the one
+    enable_compile_cache placed), or None when no cache is configured.
+    Pipelines snapshot this at stage boundaries: the DELTA is the number
+    of fresh compilations the stage paid (everything else was a cache
+    hit — the fleet's straggler metric, DESIGN.md SS10)."""
+    from repro.runtime.platform import compile_cache_dir
+
+    d = compile_cache_dir()
+    if d is None:
         return None
+    if not d.exists():  # configured; JAX creates it on the first write
+        return 0
     try:
-        return sum(1 for _ in pathlib.Path(d).iterdir())
+        return sum(1 for _ in d.iterdir())
     except OSError:
         return None
 

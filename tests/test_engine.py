@@ -1,5 +1,6 @@
 """Execution-engine layer: registry, backend agreement, optE bucketing,
 and the double-buffered chunk stream (DESIGN.md SS3/SS5)."""
+import jax
 import numpy as np
 import pytest
 
@@ -55,10 +56,20 @@ def test_use_kernels_deprecation_shim():
 
 
 # ---------------------------------------------------- oracle check harness
+def _off_chip(name: str) -> bool:
+    """pallas-compiled compiles for the TPU only: off the chip it must
+    refuse (never fall back to the interpreter)."""
+    return name == "pallas-compiled" and jax.default_backend() != "tpu"
+
+
 @pytest.mark.parametrize("name", ["reference", "pallas-interpret", "pallas-compiled"])
 def test_engine_ops_vs_oracle(name):
     from repro.engine.check import check_engine
 
+    if _off_chip(name):
+        with pytest.raises(RuntimeError, match="pallas-interpret"):
+            check_engine(name, E_max=5, Lq=96, Lc=96, seed=1)
+        return
     errs = check_engine(name, E_max=5, Lq=96, Lc=96, seed=1)
     assert set(errs) == {
         "knn_tables", "knn_tables_bucketed", "knn_tables_prefix", "ccm_lookup",
@@ -67,13 +78,18 @@ def test_engine_ops_vs_oracle(name):
 
 def test_all_engines_agree_on_synthetic_32x400():
     """Acceptance: every registered backend reproduces the reference causal
-    map on a 32x400 synthetic dataset to <= 1e-4 max |drho|."""
+    map on a 32x400 synthetic dataset to <= 1e-4 max |drho| (off the chip,
+    pallas-compiled refuses instead)."""
     cfg_ref = EDMConfig(E_max=5, engine="reference")
     ts = jnp.asarray(dummy_brain(32, 400, seed=11))
     _, optE = simplex_batch(ts, cfg_ref)
     rho_ref = np.asarray(ccm_matrix(ts, optE, cfg_ref))
     for name in engines.available_engines():
         cfg = EDMConfig(E_max=5, engine=name)
+        if _off_chip(name):
+            with pytest.raises(RuntimeError, match="backend is 'cpu'"):
+                ccm_matrix(ts, optE, cfg)
+            continue
         rho = np.asarray(ccm_matrix(ts, optE, cfg))
         err = np.abs(rho - rho_ref).max()
         assert err <= 1e-4, f"engine {name}: max |drho| {err}"
@@ -169,7 +185,9 @@ def test_ccm_lookup_kernel_crosschecks_simplex_forecast():
     idx, sqd = knn.knn_tables_dense(V, V, 6, True)
     idx, w = knn.tables_with_weights(idx, sqd)
     Y = jnp.asarray(rng.standard_normal((9, 120)), jnp.float32)
-    got = np.asarray(ccm_lookup(idx[3], w[3], Y, block_b=4, block_t=64))
+    got = np.asarray(
+        ccm_lookup(idx[3], w[3], Y, block_b=4, block_t=64, interpret=True)
+    )
     want = np.asarray(
         jnp.stack([knn.simplex_forecast(idx[3], w[3], y) for y in Y])
     )

@@ -34,7 +34,8 @@ def test_knn_topk_streaming_vs_oracle(E_max, Lq, Lc, k, exclude_self, tile_c):
         rng.standard_normal((E_max, Lc)), jnp.float32
     )
     idx, d = knn_topk_streaming(
-        Vq, Vc, k, exclude_self=exclude_self, block_q=64, tile_c=tile_c
+        Vq, Vc, k, exclude_self=exclude_self, block_q=64, tile_c=tile_c,
+        interpret=True,
     )
     ridx, rd = knn_topk_ref(Vq, Vc, k, exclude_self)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
@@ -44,7 +45,8 @@ def test_knn_topk_streaming_vs_oracle(E_max, Lq, Lc, k, exclude_self, tile_c):
 def test_knn_topk_sorted_and_self_excluded():
     rng = np.random.default_rng(7)
     V = jnp.asarray(rng.standard_normal((4, 90)), jnp.float32)
-    idx, d = knn_topk_streaming(V, V, 5, exclude_self=True, tile_c=32)
+    idx, d = knn_topk_streaming(V, V, 5, exclude_self=True, tile_c=32,
+                                interpret=True)
     d = np.asarray(d)
     idx = np.asarray(idx)
     assert np.all(np.diff(d, axis=-1) >= -1e-6)  # ascending distances
@@ -60,7 +62,9 @@ def test_ccm_lookup_vs_oracle(B, Lq, Lp, k):
     w = jnp.asarray(rng.uniform(size=(Lq, k)), jnp.float32)
     Y = jnp.asarray(rng.standard_normal((B, Lp)), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(ccm_lookup(idx, w, Y, block_b=16, block_t=64)),
+        np.asarray(
+            ccm_lookup(idx, w, Y, block_b=16, block_t=64, interpret=True)
+        ),
         np.asarray(ccm_lookup_ref(idx, w, Y)),
         rtol=1e-5, atol=1e-6,
     )
@@ -99,7 +103,8 @@ def test_flash_attn_vs_oracle(B, Sq, Sk, H, K, dh, causal, bq, bk):
     q = jnp.asarray(rng.standard_normal((B, Sq, H, dh)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, Sk, K, dh)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, Sk, K, dh)), jnp.float32)
-    o = flash_attn(q, k, v, causal=causal, block_q=bq, block_k=bk)
+    o = flash_attn(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                   interpret=True)
     r = flash_attn_ref(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=2e-5, atol=2e-5)
 
@@ -115,7 +120,7 @@ def test_flash_attn_matches_model_sdpa():
     v = jnp.asarray(rng.standard_normal((2, 128, 2, 32)), jnp.float32)
     a = _sdpa_dense(q, k, v, causal=True)
     b = _sdpa_chunked(q, k, v, causal=True, chunk=64)
-    c = flash_attn(q, k, v, causal=True, block_q=64, block_k=64)
+    c = flash_attn(q, k, v, causal=True, block_q=64, block_k=64, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=2e-5, atol=2e-5)
 

@@ -193,7 +193,8 @@ def test_stream_kernel_bit_identical_to_dense_oracle(
     Vc = Vq if exclude_self else _rand_V(E, Lc, Lc + 1)
     i0, d0 = knn_topk_ref(Vq, Vc, k, exclude_self)
     i_st, d_st = knn_topk_streaming(
-        Vq, Vc, k, exclude_self=exclude_self, block_q=block_q, tile_c=tile_c
+        Vq, Vc, k, exclude_self=exclude_self, block_q=block_q, tile_c=tile_c,
+        interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i_st))
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d_st))
@@ -204,7 +205,8 @@ def test_stream_kernel_vs_streaming_oracle():
     from repro.kernels.knn_topk.ref import knn_topk_stream_ref
 
     V = _rand_V(6, 150, 11)
-    idx, d = knn_topk_streaming(V, V, 7, exclude_self=True, block_q=64, tile_c=40)
+    idx, d = knn_topk_streaming(V, V, 7, exclude_self=True, block_q=64,
+                                tile_c=40, interpret=True)
     ridx, rd = knn_topk_stream_ref(V, V, 7, exclude_self=True, tile_c=64)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
     np.testing.assert_array_equal(np.asarray(d), np.asarray(rd))
@@ -216,7 +218,8 @@ def test_stream_kernel_ties_match_dense_oracle():
 
     V = jnp.zeros((5, 90), jnp.float32)  # dead neuron: all ties
     i0, d0 = knn_topk_ref(V, V, 6, True)
-    i_st, d_st = knn_topk_streaming(V, V, 6, exclude_self=True, block_q=32, tile_c=24)
+    i_st, d_st = knn_topk_streaming(V, V, 6, exclude_self=True, block_q=32,
+                                    tile_c=24, interpret=True)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i_st))
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d_st))
 
@@ -235,7 +238,7 @@ def test_dist_dtype_bf16_reaches_kernels():
 
     V = _rand_V(6, 120, 13)
     i_st, d_st = knn_topk_streaming(V, V, 7, exclude_self=True, block_q=64,
-                                    tile_c=40, dist_dtype="bfloat16")
+                                    tile_c=40, dist_dtype="bfloat16", interpret=True)
     assert d_st.dtype == jnp.float32  # merge keys / outputs stay f32
     _, d_f32 = knn_topk_ref(V, V, 7, True)
     assert not np.array_equal(np.asarray(d_f32), np.asarray(d_st))
@@ -266,7 +269,7 @@ def test_ragged_tail_split_covers_all_queries():
     for Lq in (130, 50, 255):
         V = _rand_V(4, Lq, Lq)
         idx, d = knn_topk_streaming(V, V, 5, exclude_self=True, block_q=128,
-                                    tile_c=64)
+                                    tile_c=64, interpret=True)
         ridx, rd = knn_topk_ref(V, V, 5, True)
         np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
         np.testing.assert_array_equal(np.asarray(d), np.asarray(rd))
@@ -288,7 +291,7 @@ def test_prefix_kernel_bit_identical_to_rebuild(tile_c):
         Vq, Vc, 7, False, buckets, lib_sizes, 64
     )
     pi, pd = knn_topk_prefix(
-        Vq, Vc, 7, False, buckets, lib_sizes, tile_c=tile_c
+        Vq, Vc, 7, False, buckets, lib_sizes, tile_c=tile_c, interpret=True
     )
     np.testing.assert_array_equal(np.asarray(pi), np.asarray(oi))
     np.testing.assert_array_equal(np.asarray(pd), np.asarray(od))
@@ -305,7 +308,8 @@ def test_prefix_kernel_col_ids_and_self_exclusion():
         V, V, 6, True, buckets, lib_sizes, 32, col_ids=cid
     )
     pi, pd = knn_topk_prefix(
-        V, V, 6, True, buckets, lib_sizes, tile_c=40, col_ids=cid
+        V, V, 6, True, buckets, lib_sizes, tile_c=40, col_ids=cid,
+        interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(pi), np.asarray(oi))
     np.testing.assert_array_equal(np.asarray(pd), np.asarray(od))
@@ -347,12 +351,13 @@ def test_stream_kernel_blocks_independent_of_Lc():
     assert "Lc" not in sig.parameters  # shape function cannot even see Lc
     assert shapes["vc_tile"] == (20, 512)
     assert shapes["scratch_idx"] == (20, 128, 21)
-    # the merge network's DOUBLED (2 * next_pow2(k)) top-k working set is
-    # part of the shape contract and the VMEM model (the budget bugfix):
-    # k=21 -> next_pow2 32 -> 64 merge lanes x (dist, id, rank) triples
-    assert shapes["merge"] == (128, 64)
-    assert stream_vmem_bytes(20, 21, 128, 512) >= (4 + 4 + 4) * 128 * 64
-    assert prefix_block_shapes(20, 3, 21, 128, 512)["merge"] == (128, 64)
+    # TPU layout: queries on sublanes, lag rows on lanes
+    assert shapes["vq"] == (128, 20)
+    # the in-kernel two-pointer merge's (id, dist) output is part of the
+    # shape contract and the VMEM model
+    assert shapes["merge"] == (128, 21)
+    assert stream_vmem_bytes(20, 21, 128, 512) >= (4 + 4) * 128 * 21
+    assert prefix_block_shapes(20, 3, 21, 128, 512)["merge"] == (128, 21)
     # paper-scale budget: E_max=20, k=21, block_q=128 fits a 16 MB VMEM
     # with headroom at ANY library length, even at the calibrator's
     # widest 4096 tile

@@ -178,3 +178,45 @@ def test_fleet_spec_platform_opt_in(tmp_path):
     """)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "spec opt-in OK" in r.stdout
+
+
+def test_fleet_driver_stays_off_the_backend(tmp_path):
+    """edm_run --workers 1 --platform cpu: the driver learns the device
+    count from a probe child and never initializes a jax backend, so on a
+    TPU host every chip stays free for its worker."""
+    out = tmp_path / "fleet"
+    r = _run_sub(f"""
+        import sys
+        from jax._src import xla_bridge
+        from repro.launch import edm_run
+        sys.argv = ["edm_run", "--synthetic", "12x200", "--e-max", "3",
+                    "--workers", "1", "--platform", "cpu",
+                    "--out", {str(out)!r}]
+        edm_run.main()
+        print("driver backend initialized:",
+              xla_bridge.backends_are_initialized())
+    """)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "driver backend initialized: False" in r.stdout
+    assert (out / "causal_map" / "data.npy").exists()
+
+
+def test_tpu_host_refuses_more_workers_than_it_can_hold(tmp_path,
+                                                        monkeypatch):
+    """A TPU worker holds every chip it sees: a local fleet of two on a
+    TPU host is refused up front instead of hanging on a held chip."""
+    from repro.launch import edm_run
+
+    monkeypatch.setattr(platform, "probe_devices", lambda tier=None: ("tpu", 1))
+    monkeypatch.setattr(sys, "argv", [
+        "edm_run", "--synthetic", "8x100", "--e-max", "3", "--workers", "2",
+        "--no-telemetry", "--out", str(tmp_path / "out"),
+    ])
+    with pytest.raises(SystemExit, match="--workers 2 on a TPU host"):
+        edm_run.main()
+    assert not (tmp_path / "out" / "fleet.json").exists()
+
+
+def test_probe_devices_reports_the_child_view():
+    plat, n = platform.probe_devices("cpu")
+    assert plat == "cpu" and n >= 1

@@ -66,7 +66,8 @@ def test_knn_topk_property(seed):
     tile_c = int(rng.integers(8, 150))
     Vq = jnp.asarray(rng.standard_normal((E_max, Lq)), jnp.float32)
     Vc = jnp.asarray(rng.standard_normal((E_max, Lc)), jnp.float32)
-    idx, d = knn_topk_streaming(Vq, Vc, k, block_q=32, tile_c=tile_c)
+    idx, d = knn_topk_streaming(Vq, Vc, k, block_q=32, tile_c=tile_c,
+                                interpret=True)
     ridx, rd = knn_topk_ref(Vq, Vc, k, False)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
     np.testing.assert_array_equal(np.asarray(d), np.asarray(rd))

@@ -283,12 +283,36 @@ def test_autotune_decision_rules(tmp_path):
 
 
 def test_compile_cache_probe(tmp_path, monkeypatch):
+    """compile_cache_entries counts the directory the placement helper
+    chose: none configured -> None; no env var -> the one fixed
+    in-checkout directory; env var set -> that directory, and the helper
+    sets nothing itself."""
+    import pathlib
+
+    import jax
+
+    from repro.runtime import platform
+
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert telemetry.compile_cache_entries() is None
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert telemetry.compile_cache_entries() is None
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert platform.enable_compile_cache() == repo / ".jax_cache"
+        assert platform.compile_cache_dir() == repo / ".jax_cache"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
     cache = tmp_path / "cache"
     cache.mkdir()
     (cache / "a").write_text("")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+    assert platform.enable_compile_cache() == cache
+    assert jax.config.jax_compilation_cache_dir == saved[keys[0]]
     assert telemetry.compile_cache_entries() == 1
     mem = telemetry.MemorySink()
     telemetry.configure(mem)
@@ -297,3 +321,35 @@ def test_compile_cache_probe(tmp_path, monkeypatch):
     (rec,) = mem.records
     assert rec["name"] == "compile_cache" and rec["value"] == 1.0
     assert rec["attrs"] == {"entries": 2, "new": 1}
+
+
+def test_compile_cache_second_run_compiles_less(tmp_path):
+    """Two processes placing the cache with enable_compile_cache under the
+    same JAX_COMPILATION_CACHE_DIR: the first writes entries there, the
+    second finds them and compiles nothing fresh."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    code = textwrap.dedent("""
+        import jax, jax.numpy as jnp
+        from repro.runtime import platform, telemetry
+        platform.enable_compile_cache()
+        before = telemetry.compile_cache_entries()
+        jax.jit(lambda x: jnp.sin(x) * 3 + x)(jnp.ones(17)).block_until_ready()
+        print("NEW", telemetry.compile_cache_entries() - before)
+    """)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    new = []
+    for _ in range(2):
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        new.append(int(r.stdout.split("NEW")[-1]))
+    assert new[0] > 0
+    assert new[1] < new[0]
+    assert any((tmp_path / "jc").iterdir())

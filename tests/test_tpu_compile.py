@@ -1,0 +1,140 @@
+"""Compile guard: the main-path Pallas kernels and the bucketed phase-2
+chunk compile for a TPU v5e chip at paper widths (Fish1_Normo Lp 1,430;
+Subject11 Lp 8,508), with ``interpret=False``.
+
+Nothing runs: the chip is described, not attached, and the TPU compiler
+refuses here what it would refuse on the chip (block shapes that break
+the (8, 128) tiling rule, ops Mosaic cannot lower, VMEM or HBM
+overflow).  The topology is described inside a fixture only — never at
+import, in a skip condition or in a parametrize argument — because one
+process at a time may load the TPU library.  Keep these tests in this
+one file.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+FISH1_N, FISH1_L = 53_053, 1_450  # configs/edm_datasets.py
+LP_FISH1, LP_SUBJECT11 = 1_430, 8_508  # L - (E_max - 1) - Tp at E_max 20
+E_MAX, K = 20, 21
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache off around it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # a Mosaic kernel is in
+    return compiled
+
+
+def _spec(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("Lp", [LP_FISH1, LP_SUBJECT11])
+@pytest.mark.parametrize("tile", ["calibrated", 384])
+def test_stream_kernel_compiles_for_v5e(one_chip, Lp, tile):
+    from repro.core.knn import calibrate_knn_tile
+    from repro.kernels.knn_topk.knn_topk import knn_topk_stream_pallas
+
+    tile_c = calibrate_knn_tile(Lp) if tile == "calibrated" else tile
+    _compile(
+        lambda V: knn_topk_stream_pallas(
+            V, V, K, True, tile_c=tile_c, interpret=False
+        ),
+        _spec((E_MAX, Lp), one_chip),
+    )
+
+
+@pytest.mark.parametrize("Lp", [LP_FISH1, LP_SUBJECT11])
+def test_prefix_kernel_compiles_for_v5e(one_chip, Lp):
+    from repro.core.knn import calibrate_knn_tile
+    from repro.kernels.knn_topk.knn_topk import knn_topk_prefix_pallas
+
+    lib_sizes = (Lp // 4, Lp // 2, Lp)
+    _compile(
+        lambda V: knn_topk_prefix_pallas(
+            V, V, K, True, (3, 8, 20), lib_sizes,
+            tile_c=calibrate_knn_tile(Lp), interpret=False,
+        ),
+        _spec((E_MAX, Lp), one_chip),
+    )
+
+
+@pytest.mark.parametrize("Lp", [LP_FISH1, LP_SUBJECT11])
+def test_ccm_lookup_kernel_compiles_for_v5e(one_chip, Lp):
+    from repro.kernels.ccm_lookup.ccm_lookup import ccm_lookup_pallas
+
+    _compile(
+        lambda i, w, y: ccm_lookup_pallas(i, w, y, block_b=32, interpret=False),
+        _spec((Lp, K), one_chip, jnp.int32),
+        _spec((Lp, K), one_chip),
+        _spec((300, Lp), one_chip),
+    )
+
+
+def test_bucketed_phase2_chunk_fits_v5e_at_fish1_width(topo, monkeypatch):
+    """The pallas-compiled bucketed phase-2 chunk (what edm_run runs per
+    row chunk) at Fish1_Normo width: one chip's mesh, every optE bucket
+    present, fits the chip's 16 GB HBM."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import EDMConfig, ccm, pipeline
+    from repro.engine.pallas import PallasEngine
+
+    # jax.default_backend() is the CPU here; steer the engine to compile
+    # its kernels natively for the described chip.
+    monkeypatch.setattr(PallasEngine, "_interpret", lambda self: False)
+    mesh = Mesh(np.array(topo.devices[:1]), ("workers",))
+    cfg = EDMConfig(E_max=E_MAX, engine="pallas-compiled")
+    optE = (np.arange(FISH1_N) % E_MAX + 1).astype(np.int32)
+    plan, _ = ccm.make_bucket_plan(optE)
+    fn = pipeline.make_ccm_chunk_fn_bucketed(mesh, cfg, plan)
+    Lp = cfg.n_points(FISH1_L)
+    assert Lp == LP_FISH1
+    rows = _spec((cfg.lib_block, FISH1_L),
+                 NamedSharding(mesh, P("workers", None)))
+    fut = _spec((FISH1_N, Lp), NamedSharding(mesh, P(None, None)))
+    compiled = fn.lower(rows, fut).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    total = (m.temp_size_in_bytes + m.argument_size_in_bytes
+             + m.output_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 1e9:.2f} GB"
