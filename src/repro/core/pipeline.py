@@ -341,29 +341,45 @@ def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
     return np.concatenate([a, pad], axis=0)
 
 
+def _dispatch(unit: dict, row0: int, fn, *args):
+    """``fn(*args)``, a jitted call, under a ``phase2/dispatch`` span.  While
+    the unit's first chunk runs, its time adds to the unit's
+    ``first_dispatch_s``: trace, lower and compile, or cache load."""
+    with telemetry.span("phase2", "dispatch", row0=row0):
+        t0 = _perf()
+        out = fn(*args)
+        if unit["chunks"] == 0:
+            unit["first_dispatch_s"] += _perf() - t0
+    return out
+
+
 def _phase2_untiled(
     ts, ts_fut, optE, cfg, mesh, chunk, chunk_plan, writer, rho, progress,
-    on_chunk=None,
+    unit, on_chunk=None,
 ):
     """Legacy single-tile phase 2: full-width (chunk, N) row blocks."""
     N = ts.shape[0]
+    t0 = _perf()
     if cfg.bucketed:
         plan, order = ccm.make_bucket_plan(optE)
         inv = np.argsort(order)
         chunk_fn = make_ccm_chunk_fn_bucketed(mesh, cfg, plan)
         ts_fut_j = jnp.asarray(ts_fut[order])
-        dispatch = lambda rows: chunk_fn(jnp.asarray(rows), ts_fut_j)
-        unsort = lambda rho_rows: rho_rows[:, inv]
+        args = (ts_fut_j,)
     else:
+        inv = None
         chunk_fn = make_ccm_chunk_fn(mesh, cfg)
         ts_fut_j = jnp.asarray(ts_fut)
-        optE_j = jnp.asarray(optE)
-        dispatch = lambda rows: chunk_fn(jnp.asarray(rows), ts_fut_j, optE_j)
-        unsort = lambda rho_rows: rho_rows
+        args = (ts_fut_j, jnp.asarray(optE))
+    unit["prep_s"] = _perf() - t0
+    unit["futures_bytes"] = int(ts_fut_j.nbytes)
 
     def drain(tag, rho_rows):
         row0, valid = tag
-        rows_np = unsort(rho_rows)[:valid]
+        if inv is not None:
+            with telemetry.span("phase2", "unsort", row0=row0):
+                rho_rows = rho_rows[:, inv]
+        rows_np = rho_rows[:valid]
         if writer is not None:
             writer.write_block(row0, rows_np)
         else:
@@ -380,20 +396,23 @@ def _phase2_untiled(
                                 rows=valid, tiled=False) as t:
                 with telemetry.span("phase2", "device_put", row0=row0):
                     rows = jnp.asarray(_pad_rows(ts[row0 : row0 + chunk], chunk))
-                dev = dispatch(rows)
+                dev = _dispatch(unit, row0, chunk_fn, rows, *args)
                 t["chunk_rows"] = chunk
+            unit["chunks"] += 1
+            unit["rows"] += valid
             streamer.submit((row0, valid), dev)
 
 
 def _phase2_tiled(
     ts, ts_fut, optE, cfg, mesh, chunk, chunk_plan, writer, rho, progress,
-    on_chunk=None,
+    unit, on_chunk=None,
 ):
     """2D (row-chunk x col-tile) phase 2: tables once per chunk, targets in
     column tiles of cfg.target_tile, blocks streamed with
     (row0, col0, valid) tags."""
     N = ts.shape[0]
     T = cfg.target_tile
+    t0 = _perf()
     if cfg.bucketed:
         plan, order = ccm.make_bucket_plan(optE)
         tables_fn = make_ccm_tables_fn_bucketed(mesh, cfg, plan)
@@ -409,6 +428,8 @@ def _phase2_tiled(
         e_idx_host = optE.astype(np.int32) - 1
         if writer is not None:
             writer.ensure_col_order(None)
+    unit["prep_s"] = _perf() - t0
+    unit["futures_bytes"] = 0  # tiles go up per chunk, counted below
 
     def drain(tag, block):
         row0, col0, valid = tag
@@ -436,7 +457,7 @@ def _phase2_tiled(
                                 n_tiles=len(tile_plans)) as t:
                 with telemetry.span("phase2", "device_put", row0=row0):
                     rows = jnp.asarray(_pad_rows(ts[row0 : row0 + chunk], chunk))
-                idx, w = tables_fn(rows)  # once per chunk
+                idx, w = _dispatch(unit, row0, tables_fn, rows)  # once per chunk
                 for c0, seg_plan in tile_plans:
                     c1 = min(c0 + T, N)
                     # per-tile slice only — a gather through `order` in the
@@ -444,14 +465,19 @@ def _phase2_tiled(
                     fut_tile = jnp.asarray(
                         ts_fut[order[c0:c1]] if order is not None else ts_fut[c0:c1]
                     )
+                    unit["futures_bytes"] += int(fut_tile.nbytes)
                     if seg_plan is not None:
-                        block = tile_fn_for(seg_plan)(idx, w, fut_tile)
+                        block = _dispatch(unit, row0, tile_fn_for(seg_plan),
+                                          idx, w, fut_tile)
                     else:
-                        block = tile_fn(
-                            idx, w, fut_tile, jnp.asarray(e_idx_host[c0:c1])
+                        block = _dispatch(
+                            unit, row0, tile_fn, idx, w, fut_tile,
+                            jnp.asarray(e_idx_host[c0:c1]),
                         )
                     streamer.submit((row0, c0, valid), block)
                 t["chunk_rows"] = chunk
+            unit["chunks"] += 1
+            unit["rows"] += valid
     if writer is not None:
         writer.commit()  # defensive: deferred entries are never left behind
 
@@ -513,12 +539,19 @@ def run_phase2_chunks(
     blocks to the store; with ``rho`` they land in a host map instead.
     ``on_chunk(row0)`` fires before each chunk dispatch — fleet workers
     renew their unit lease there, same contract as :func:`run_phase1`.
+
+    The call is one ``phase2/unit`` span with the unit's set-up costs:
+    ``prep_s`` (bucket plan, futures gathered, their upload started),
+    ``futures_bytes``, ``first_dispatch_s`` (the first chunk's jitted
+    calls), ``chunks`` and ``rows``.
     """
     chunk = mesh.size * cfg.lib_block
     phase2 = _phase2_tiled if cfg.target_tile else _phase2_untiled
     cache0 = telemetry.compile_cache_entries()
-    phase2(ts, ts_fut, optE, cfg, mesh, chunk, chunk_plan, writer, rho,
-           progress, on_chunk=on_chunk)
+    with telemetry.span("phase2", "unit") as unit:
+        unit.update(chunks=0, rows=0, first_dispatch_s=0.0)
+        phase2(ts, ts_fut, optE, cfg, mesh, chunk, chunk_plan, writer, rho,
+               progress, unit, on_chunk=on_chunk)
     telemetry.emit_compile_cache("phase2", cache0)
 
 

@@ -19,30 +19,6 @@ import jax
 
 from repro.engine.base import Engine
 
-# knn_tables is entered at jit-trace time, so each distinct kernel shape
-# emits its VMEM working set exactly once per compile — dedupe beyond
-# that keeps recompiles (cache misses) visible.
-_vmem_seen: set = set()
-
-
-def _emit_vmem(E_max: int, k: int, tile: int, cfg) -> None:
-    from repro.runtime import telemetry
-
-    if not telemetry.enabled():
-        return
-    key = (E_max, k, tile, cfg.dist_dtype)
-    if key in _vmem_seen:
-        return
-    _vmem_seen.add(key)
-    from repro.kernels.knn_topk.knn_topk import stream_vmem_bytes
-
-    telemetry.counter(
-        "engine", "vmem_working_set",
-        float(stream_vmem_bytes(E_max, k, 128, tile, cfg.dist_dtype)),
-        E_max=E_max, k=k, tile_c=tile, dist_dtype=str(cfg.dist_dtype),
-    )
-
-
 class PallasEngine(Engine):
     """The kernels compiled for the TPU; raises on any other backend."""
 
@@ -65,7 +41,6 @@ class PallasEngine(Engine):
         # Streaming kernel (DESIGN.md SS8): per-program VMEM is flat in
         # Lc, so library length is HBM-bound, not VMEM-bound.
         tile = self.knn_selection_tile(Vc.shape[1], cfg)
-        _emit_vmem(Vq.shape[0], k, tile, cfg)
         return knn_topk_streaming(
             Vq, Vc, k, exclude_self=exclude_self, tile_c=tile,
             dist_dtype=cfg.dist_dtype, interpret=self._interpret(),

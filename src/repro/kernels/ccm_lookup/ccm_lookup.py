@@ -115,6 +115,7 @@ def ccm_lookup_pallas(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         interpret=interpret,
+        name="ccm_lookup",
     )(idx_p, w_p, Y_t)
     out = out[:, :Lq, :B].transpose(0, 2, 1)
     return out[0] if single else out

@@ -445,6 +445,7 @@ def knn_topk_stream_pallas(
             scratch_shapes=scratch,
             compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
+            name="knn_topk_stream",
         )(VqT_p, Vc_p)
 
     return _over_query_splits(Vq, block_q, call_split)
@@ -663,6 +664,7 @@ def knn_topk_prefix_pallas(
             ],
             compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
+            name="knn_topk_prefix",
         )(slot_arr, VqT_p, Vc_g, ids)
 
     return _over_query_splits(Vq[:E_hi], block_q, call_split, q_axis=2)

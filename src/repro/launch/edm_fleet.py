@@ -526,7 +526,8 @@ def fleet_status(out_dir: str | pathlib.Path) -> dict:
             {"span_s": 0.0, "claim": 0, "steal": 0, "done": 0},
         )
         if rec["kind"] == "span":
-            st["span_s"] += rec["dur_s"]
+            if rec["name"] not in telemetry.NESTED_SPANS:
+                st["span_s"] += rec["dur_s"]
         elif rec["name"] in ("claim", "steal", "done"):
             st[rec["name"]] += 1
     for st in per_stage.values():

@@ -138,8 +138,9 @@ def build_record(out_dir: str | pathlib.Path) -> dict:
         stage, name, attrs = r["stage"], r["name"], r["attrs"] or {}
         if r["kind"] == "span":
             st = rec["stages"].setdefault(stage, {"span_s": 0.0})
-            st["span_s"] += r["dur_s"]
-            rec["total_span_s"] += r["dur_s"]
+            if name not in telemetry.NESTED_SPANS:
+                st["span_s"] += r["dur_s"]
+                rec["total_span_s"] += r["dur_s"]
             if name == "chunk":
                 chunk_durs.append(r["dur_s"])
                 if stage in ("phase2", "sig"):

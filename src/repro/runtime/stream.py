@@ -67,12 +67,24 @@ class ChunkStreamer:
 
     def _drain_one(self) -> None:
         tag, dev = self._pending.popleft()
+        label = repr(tag)
         with telemetry.span(self.stage, "drain",
-                            tag=repr(tag), in_flight=len(self._pending),
+                            tag=label, in_flight=len(self._pending),
                             depth=self.depth) as t:
-            t0 = _perf()
-            host = np.asarray(dev)  # blocks: compute + D2H copy
-            t["gather_s"] = _perf() - t0
+            # gather_s = wait for the device + copy to the host; the two
+            # child spans split it.
+            with telemetry.span(self.stage, "device_wait", tag=label):
+                t0 = _perf()
+                wait = getattr(dev, "block_until_ready", None)
+                if wait is not None:
+                    wait()
+                wait_s = _perf() - t0
+            with telemetry.span(self.stage, "d2h_copy", tag=label) as c:
+                t0 = _perf()
+                host = np.asarray(dev)
+                copy_s = _perf() - t0
+                c["bytes"] = int(host.nbytes)
+            t["gather_s"] = wait_s + copy_s
             t["bytes"] = int(host.nbytes)
             self.drain(tag, host)
 

@@ -6,7 +6,9 @@ knobs (chunk rows, target_tile, knn_tile_c) whose values are invisible
 at runtime.  This module records WHERE the wall time goes, as structured
 records every layer can emit without knowing who is listening:
 
-  * :func:`span` — a timed context manager (``dur_s`` stamped on exit);
+  * :func:`span` — a timed context manager (``dur_s`` stamped on exit),
+    also entered as a ``jax.profiler.TraceAnnotation`` so a profiler
+    trace holds it on the device operations' clock;
   * :func:`counter` — a point event with a value (claims, steals, bytes,
     cache entries, calibration results).
 
@@ -67,13 +69,19 @@ import pathlib
 import sys
 import threading
 import time
-from typing import Any, Callable, Iterable, Iterator
+from typing import Iterator
 
 #: pipeline stages every full run walks (the "five stages" of the fleet);
 #: validate() additionally accepts the runtime layers below.
 PIPELINE_STAGES = ("phase1", "phase2", "assemble", "sig", "finalize")
 RUNTIME_STAGES = ("queue", "store", "stream", "engine", "fleet")
 SCHEMA_VERSION = 1
+#: spans that re-time seconds a span of their own stage already holds
+#: (the whole unit; a chunk's dispatch; a drain's wait, copy and
+#: un-sort).  The per-stage span totals (`edm_fleet status`, the
+#: trace's ``span_totals``, history's ``total_span_s``) leave them out.
+NESTED_SPANS = frozenset({"unit", "dispatch", "device_wait", "d2h_copy",
+                          "unsort"})
 
 _lock = threading.Lock()
 _sinks: list["Sink"] = []
@@ -293,27 +301,36 @@ def emit_clock_anchor(**attrs) -> None:
             epoch=time.time(), mono=time.monotonic(), **attrs)
 
 
+def _annotation(label: str):
+    """``jax.profiler.TraceAnnotation(label)`` when JAX is already loaded,
+    else a no-op: telemetry never imports JAX itself, so a process that
+    does not use it (the fleet parent) stays off the backend."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(label)
+
+
 @contextlib.contextmanager
 def span(stage: str, name: str, **attrs):
     """Timed region; ``dur_s`` is wall time between enter and exit.  The
     yielded dict lets the body add attrs discovered mid-span (e.g. fsync
-    time, tile count).  Emits nothing when no sink is configured."""
+    time, tile count).  Emits nothing when no sink is configured.
+
+    Under a sink the span also enters a profiler annotation named
+    ``<stage>/<name>``, so a ``jax.profiler`` trace shows it in the host
+    plane, on the device operations' clock."""
     if not _sinks:
         yield {}
         return
     extra: dict = {}
-    t0 = time.perf_counter()
-    try:
-        yield extra
-    finally:
-        _emit("span", stage, name, dur_s=time.perf_counter() - t0,
-              attrs={**attrs, **extra})
-
-
-def timed(stage: str, name: str, fn: Callable, *args, **attrs):
-    """Run ``fn(*args)`` under a span; returns fn's result."""
-    with span(stage, name, **attrs):
-        return fn(*args)
+    with _annotation(f"{stage}/{name}"):
+        t0 = time.perf_counter()
+        try:
+            yield extra
+        finally:
+            _emit("span", stage, name, dur_s=time.perf_counter() - t0,
+                  attrs={**attrs, **extra})
 
 
 # ------------------------------------------------------------- validation

@@ -224,7 +224,8 @@ def assemble_trace(out_dir: str | pathlib.Path) -> dict:
       critical_path  one entry per stage walked: the unit the barrier
                      waited on, with queue_wait/compute/gather/store/
                      straggler_tail seconds
-      span_totals    stage -> sum of ALL span dur_s (the exact
+      span_totals    stage -> sum of span dur_s, NESTED_SPANS left
+                     out (the exact
                      aggregation `edm_fleet status` reports — the
                      reconciliation surface)
       total_wall_s   aligned end - start over every record
@@ -275,7 +276,9 @@ def assemble_trace(out_dir: str | pathlib.Path) -> dict:
             t_min, t_max = min(t_min, start), max(t_max, end)
             stage, name, attrs = r["stage"], r["name"], r["attrs"]
             if r["kind"] == "span":
-                span_totals[stage] = span_totals.get(stage, 0.0) + r["dur_s"]
+                if name not in telemetry.NESTED_SPANS:
+                    span_totals[stage] = (span_totals.get(stage, 0.0)
+                                          + r["dur_s"])
                 if name == "stage":
                     stage_span[(w, stage)] = (start, end)
                 elif name == "chunk":
@@ -524,9 +527,10 @@ def assemble_trace(out_dir: str | pathlib.Path) -> dict:
 
 
 # -------------------------------------------------------- chrome trace JSON
-_LANES = {"stage": 0, "chunk": 1, "device_put": 1, "drain": 2,
-          "write_tile": 3, "write_block": 3, "manifest_commit": 3,
-          "causal_map": 1, "store": 1}
+_LANES = {"stage": 0, "unit": 0, "chunk": 1, "device_put": 1,
+          "dispatch": 1, "drain": 2, "device_wait": 2, "d2h_copy": 2,
+          "unsort": 2, "write_tile": 3, "write_block": 3,
+          "manifest_commit": 3, "causal_map": 1, "store": 1}
 _LANE_NAMES = {0: "barrier", 1: "compute", 2: "drain", 3: "store",
                9: "events"}
 
@@ -594,7 +598,7 @@ def write_chrome_trace(
 # ------------------------------------------------------------ reconciliation
 def reconcile(trace: dict, status: dict) -> dict:
     """Per-stage span totals: trace vs `edm_fleet status` (both sum the
-    dur_s of every valid span record per stage — any drift means the
+    dur_s of every valid span record per stage but NESTED_SPANS — any drift means the
     two readers disagree about the same files).  ``ok`` when every
     common stage matches within 1%."""
     out: dict[str, Any] = {"stages": {}, "ok": True}

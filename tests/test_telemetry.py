@@ -353,3 +353,221 @@ def test_compile_cache_second_run_compiles_less(tmp_path):
     assert new[0] > 0
     assert new[1] < new[0]
     assert any((tmp_path / "jc").iterdir())
+
+
+# ------------------------------------------------------ phase-2 spans
+def _phase2_unit(tmp_path, sink, target_tile=0):
+    """A tiny bucketed ``run_phase2_chunks`` at stream_depth 2 through a
+    TileWriter, its last chunk partial; returns (plan, written bytes)."""
+    import jax.numpy as jnp
+
+    from repro.core import EDMConfig, ccm
+    from repro.core.pipeline import default_mesh, run_phase2_chunks
+    from repro.data.store import TileWriter
+    from repro.data.synthetic import dummy_brain
+
+    N, rows = 4096, 11
+    ts = dummy_brain(N, 120, seed=5)
+    cfg = EDMConfig(E_max=4, lib_block=2, stream_depth=2,
+                    target_tile=target_tile)
+    fut = np.asarray(ccm.all_futures(jnp.asarray(ts), cfg))
+    optE = (np.arange(N) % 4 + 1).astype(np.int32)
+    mesh = default_mesh()
+    chunk = mesh.size * cfg.lib_block
+    plan = [(r, min(chunk, rows - r)) for r in range(0, rows, chunk)]
+    if sink is not None:
+        telemetry.configure(sink)
+    writer = TileWriter(tmp_path / "rho", N)
+    run_phase2_chunks(ts, fut, optE, cfg, mesh, plan, writer=writer)
+    telemetry.configure()
+    written = b"".join(f.read_bytes()
+                       for f in sorted(writer.dir.glob("*_*.npy")))
+    return plan, written
+
+
+def _interval(rec):
+    return rec["mono"] - rec["dur_s"], rec["mono"]
+
+
+def _within(inner, outer, eps=1e-4):
+    a, b = _interval(inner)
+    c, d = _interval(outer)
+    return c - eps <= a and b <= d + eps
+
+
+def test_phase2_drain_splits_into_wait_copy_unsort_and_store(tmp_path):
+    from repro.runtime.trace import _tag_row0
+
+    mem = telemetry.MemorySink()
+    plan, _ = _phase2_unit(tmp_path, mem)
+    spans = [r for r in mem.records if r["kind"] == "span"]
+    for r in spans:
+        assert telemetry.validate(r) == [], r
+    drains = [r for r in spans if r["name"] == "drain"]
+    assert [_tag_row0(d["attrs"]) for d in drains] == [r for r, _ in plan]
+    children = ("device_wait", "d2h_copy", "unsort", "write_block",
+                "manifest_commit")
+    for d in drains:
+        row0 = _tag_row0(d["attrs"])
+        inside = [r for r in spans if r["name"] in children
+                  and _within(r, d)]
+        by_name = {}
+        for r in inside:
+            by_name.setdefault(r["name"], []).append(r)
+        assert sorted(by_name) == sorted(children), by_name
+        assert all(len(v) == 1 for v in by_name.values()), by_name
+        (wait,), (copy,) = by_name["device_wait"], by_name["d2h_copy"]
+        for r in (wait, copy, by_name["unsort"][0]):
+            assert r["stage"] == "phase2" and _tag_row0(r["attrs"]) == row0
+        assert _interval(wait)[1] <= _interval(copy)[0] + 1e-4
+        assert copy["attrs"]["bytes"] == d["attrs"]["bytes"] > 0
+        # gather_s is the wait and the copy, as before the split
+        assert d["attrs"]["gather_s"] == pytest.approx(
+            wait["dur_s"] + copy["dur_s"], abs=1e-4)
+        covered = sum(r["dur_s"] for r in inside)
+        assert covered >= 0.95 * d["dur_s"], (covered, d["dur_s"])
+
+
+def test_phase2_span_totals_leave_the_split_out(tmp_path):
+    """The per-stage span totals (trace, history; `edm_fleet status` sums
+    the same way) hold the spans the phase-2 path emitted before the
+    drain, dispatch and unit were split out, and no more."""
+    from repro.runtime import history, trace
+
+    out = tmp_path / "run"
+    _phase2_unit(tmp_path,
+                 telemetry.JsonlSink(telemetry.worker_jsonl(out, "w0")))
+    spans = [r for _, r in telemetry.iter_store_records(out)
+             if r["kind"] == "span"]
+    names = {r["name"] for r in spans}
+    assert telemetry.NESTED_SPANS <= names
+    before_split = {"chunk", "device_put", "drain", "write_block",
+                    "manifest_commit"}
+    assert names - telemetry.NESTED_SPANS == before_split
+    want: dict = {}
+    for r in spans:
+        if r["name"] in before_split:
+            want[r["stage"]] = want.get(r["stage"], 0.0) + r["dur_s"]
+    nested = sum(r["dur_s"] for r in spans
+                 if r["name"] in telemetry.NESTED_SPANS)
+    assert nested > 0.5 * want["phase2"]
+
+    rec = history.build_record(out)
+    assert rec["total_span_s"] == pytest.approx(sum(want.values()),
+                                                abs=1e-5)
+    for stage, s in want.items():
+        assert rec["stages"][stage]["span_s"] == pytest.approx(s, abs=1e-5)
+    tr = trace.assemble_trace(out)
+    assert tr["span_totals"] == pytest.approx(want, abs=1e-6)
+
+
+@pytest.mark.parametrize("target_tile", [0, 1500])
+def test_phase2_dispatch_nests_in_chunk_and_one_unit_per_call(
+        tmp_path, target_tile):
+    mem = telemetry.MemorySink()
+    plan, _ = _phase2_unit(tmp_path, mem, target_tile)
+    spans = [r for r in mem.records if r["kind"] == "span"
+             and r["stage"] == "phase2"]
+    chunks = [r for r in spans if r["name"] == "chunk"]
+    dispatches = [r for r in spans if r["name"] == "dispatch"]
+    # untiled: one call a chunk; tiled: the tables and each column tile
+    per_chunk = 1 + (-(-4096 // target_tile) if target_tile else 0)
+    assert len(chunks) == len(plan)
+    assert len(dispatches) == per_chunk * len(plan)
+    for i, c in enumerate(chunks):
+        for d in dispatches[i * per_chunk:(i + 1) * per_chunk]:
+            assert d["attrs"]["row0"] == c["attrs"]["row0"]
+            assert _within(d, c)
+    (unit,) = [r for r in spans if r["name"] == "unit"]
+    a = unit["attrs"]
+    assert a["chunks"] == len(plan)
+    assert a["rows"] == sum(n for _, n in plan)
+    assert a["prep_s"] > 0 and a["first_dispatch_s"] > 0
+    first = sum(d["dur_s"] for d in dispatches[:per_chunk])
+    assert a["first_dispatch_s"] == pytest.approx(first, abs=1e-3)
+    assert a["futures_bytes"] > 0
+    assert all(_within(r, unit) for r in spans)
+
+
+def test_phase2_without_sink_records_nothing_and_writes_the_same(
+        tmp_path, monkeypatch):
+    import jax
+
+    entered = []
+    real = jax.profiler.TraceAnnotation
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: entered.append(name) or real(name))
+    seq = telemetry._seq
+    _, off = _phase2_unit(tmp_path / "off", None)
+    assert telemetry._seq == seq and entered == []
+    mem = telemetry.MemorySink()
+    _, on = _phase2_unit(tmp_path / "on", mem)
+    assert off == on
+    assert len(entered) == len(mem.records) - sum(
+        r["kind"] == "counter" for r in mem.records)
+
+
+def test_span_lands_in_the_profiler_host_plane_on_the_trace_clock(
+        tmp_path):
+    """A span under a sink is also an event of the profiler's host plane;
+    its start there, measured from the benchmark's anchor annotation,
+    agrees with where the sink's record puts it."""
+    import importlib.util
+    import pathlib
+    import time
+
+    import jax
+
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+            / "chip" / "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("trace_reduce_t", path)
+    tr = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tr)
+
+    mem = telemetry.MemorySink()
+    telemetry.configure(mem)
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        anchor = time.monotonic()
+        with jax.profiler.TraceAnnotation(tr.ANCHOR):
+            pass
+        time.sleep(0.02)
+        with telemetry.span("phase2", "device_wait", row0=8):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    prof = tr.load(tr.find_xplane(tmp_path / "trace"))
+    events = [ev for p in prof.planes if p.name.startswith("/host")
+              for ln in p.lines for ev in ln.events
+              if ev.name == "phase2/device_wait"]
+    assert len(events) == 1
+    (rec,) = mem.records
+    got = (events[0].start_ns - tr.anchor_ns(prof)) * 1e-9
+    want = rec["mono"] - rec["dur_s"] - anchor
+    assert got == pytest.approx(want, abs=1e-3)
+    assert got > 0.015
+
+
+def test_telemetry_never_imports_jax():
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    code = textwrap.dedent("""
+        import sys
+        from repro.runtime import telemetry
+        mem = telemetry.MemorySink()
+        telemetry.configure(mem)
+        with telemetry.span("fleet", "stage"):
+            pass
+        assert len(mem.records) == 1
+        print("JAX", "jax" in sys.modules)
+    """)
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split()[-2:] == ["JAX", "False"]

@@ -11,6 +11,7 @@ process at a time may load the TPU library.  Keep these tests in this
 one file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -133,7 +134,21 @@ def test_bucketed_phase2_chunk_fits_v5e_at_fish1_width(topo, monkeypatch):
                  NamedSharding(mesh, P("workers", None)))
     fut = _spec((FISH1_N, Lp), NamedSharding(mesh, P(None, None)))
     compiled = fn.lower(rows, fut).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The kernels keep their own names in the compiled program (the names
+    # the device trace gives their operations), whatever wraps them.
+    kernels = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+        if m is None:
+            continue
+        name = re.sub(r"(\.\d+)+$", "", m.group(1))
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.add(name)
+        else:
+            assert "ccm_lookup" not in name and "knn_topk_stream" not in name
+    assert kernels == {"ccm_lookup", "knn_topk_stream"}, kernels
     m = compiled.memory_analysis()
     total = (m.temp_size_in_bytes + m.argument_size_in_bytes
              + m.output_size_in_bytes)
