@@ -43,6 +43,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import engine as engines
 from repro.core import ccm, knn, simplex
 from repro.core.types import CausalMap, EDMConfig
 from repro.data.store import TileWriter
@@ -543,13 +544,21 @@ def run_phase2_chunks(
     The call is one ``phase2/unit`` span with the unit's set-up costs:
     ``prep_s`` (bucket plan, futures gathered, their upload started),
     ``futures_bytes``, ``first_dispatch_s`` (the first chunk's jitted
-    calls), ``chunks`` and ``rows``.
+    calls), ``chunks`` and ``rows``; and, where the engine runs a lookup
+    kernel, ``lookup_sublanes``: the sublanes of the target tile its
+    full ``target_block`` lookups add per neighbour step.
     """
     chunk = mesh.size * cfg.lib_block
     phase2 = _phase2_tiled if cfg.target_tile else _phase2_untiled
     cache0 = telemetry.compile_cache_entries()
+    sublanes = engines.get_engine(cfg.engine).lookup_sublanes(
+        min(cfg.target_block, cfg.target_tile or ts.shape[0]),
+        ts_fut.shape[1],
+    )
     with telemetry.span("phase2", "unit") as unit:
         unit.update(chunks=0, rows=0, first_dispatch_s=0.0)
+        if sublanes is not None:
+            unit["lookup_sublanes"] = sublanes
         phase2(ts, ts_fut, optE, cfg, mesh, chunk, chunk_plan, writer, rho,
                progress, unit, on_chunk=on_chunk)
     telemetry.emit_compile_cache("phase2", cache0)

@@ -125,6 +125,12 @@ class Engine:
         """
         return jax.vmap(lambda y: self.simplex_forecast(idx, w, y))(Y_fut)
 
+    def lookup_sublanes(self, B: int, Lp: int):
+        """Sublanes of the target tile one neighbour step of a batched
+        lookup of B targets by Lp futures adds, where the engine runs a
+        lookup kernel; None where it runs none (this composition)."""
+        return None
+
     # ------------------------------------------------------------ misc
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return f"<Engine {self.name}>"

@@ -69,6 +69,11 @@ class PallasEngine(Engine):
 
         return ccm_lookup(idx, w, Y_fut, interpret=self._interpret())
 
+    def lookup_sublanes(self, B, Lp):
+        from repro.kernels.ccm_lookup.ccm_lookup import lookup_tile
+
+        return lookup_tile(B, Lp)[0]  # ccm_lookup's default blocks
+
 
 class PallasInterpretEngine(PallasEngine):
     """The same kernels in the Pallas interpreter, on any backend."""

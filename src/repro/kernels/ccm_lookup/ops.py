@@ -53,7 +53,7 @@ def ccm_lookup(
     idx: jax.Array,
     w: jax.Array,
     Y_fut: jax.Array,
-    block_b: int = 128,
+    block_b: int = 1024,
     block_t: int = 256,
     *,
     interpret: bool,

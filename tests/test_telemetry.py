@@ -489,6 +489,38 @@ def test_phase2_dispatch_nests_in_chunk_and_one_unit_per_call(
     assert all(_within(r, unit) for r in spans)
 
 
+@pytest.mark.parametrize("engine,sublanes", [("pallas-interpret", 2),
+                                              ("reference", None)])
+def test_phase2_unit_carries_lookup_sublanes(engine, sublanes):
+    """Where the engine runs the lookup kernel, the unit span says how
+    many sublanes of targets each neighbour step adds, as the kernel's
+    wrapper picks them: 300 targets, so a 256-target (2, 128) tile."""
+    import jax.numpy as jnp
+
+    from repro.core import EDMConfig, ccm
+    from repro.core.pipeline import default_mesh, run_phase2_chunks
+    from repro.data.synthetic import dummy_brain
+
+    N = 300
+    ts = dummy_brain(N, 60, seed=3)
+    cfg = EDMConfig(E_max=3, lib_block=2, engine=engine)
+    fut = np.asarray(ccm.all_futures(jnp.asarray(ts), cfg))
+    optE = (np.arange(N) % 3 + 1).astype(np.int32)
+    mesh = default_mesh()
+    rho = np.zeros((N, N), np.float32)
+    mem = telemetry.MemorySink()
+    telemetry.configure(mem)
+    try:
+        run_phase2_chunks(ts, fut, optE, cfg, mesh,
+                          [(0, mesh.size * cfg.lib_block)], rho=rho)
+    finally:
+        telemetry.configure()
+    (unit,) = [r for r in mem.records
+               if r["kind"] == "span" and r["name"] == "unit"]
+    assert unit["attrs"].get("lookup_sublanes") == sublanes
+    assert telemetry.validate(unit) == []
+
+
 def test_phase2_without_sink_records_nothing_and_writes_the_same(
         tmp_path, monkeypatch):
     import jax

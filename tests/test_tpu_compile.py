@@ -99,15 +99,32 @@ def test_prefix_kernel_compiles_for_v5e(one_chip, Lp):
     )
 
 
-@pytest.mark.parametrize("Lp", [LP_FISH1, LP_SUBJECT11])
-def test_ccm_lookup_kernel_compiles_for_v5e(one_chip, Lp):
-    from repro.kernels.ccm_lookup.ccm_lookup import ccm_lookup_pallas
+@pytest.mark.parametrize("Lp,blocks", [
+    pytest.param(LP_FISH1, "block_b=32", id=str(LP_FISH1)),
+    pytest.param(LP_SUBJECT11, "block_b=32", id=str(LP_SUBJECT11)),
+    pytest.param(LP_FISH1, "default", id=f"{LP_FISH1}-default"),
+    pytest.param(LP_SUBJECT11, "default", id=f"{LP_SUBJECT11}-default"),
+])
+def test_ccm_lookup_kernel_compiles_for_v5e(one_chip, Lp, blocks):
+    """block_b=32: one table, 300 targets in single-sublane blocks.
+    default: the blocks the wrapper picks by itself for a phase-2 call,
+    8 tables (a lib_block of rows) x target_block 2,048 targets — (8, 128)
+    target tiles, whose Subject11 futures block overflows the default
+    scoped VMEM unless the kernel raises its limit."""
+    from repro.kernels.ccm_lookup.ccm_lookup import (
+        ccm_lookup_pallas, lookup_tile,
+    )
 
+    if blocks == "default":
+        tables, B, kw = (8, Lp, K), 2048, {}
+        assert lookup_tile(B, Lp)[0] == 8
+    else:
+        tables, B, kw = (Lp, K), 300, {"block_b": 32}
     _compile(
-        lambda i, w, y: ccm_lookup_pallas(i, w, y, block_b=32, interpret=False),
-        _spec((Lp, K), one_chip, jnp.int32),
-        _spec((Lp, K), one_chip),
-        _spec((300, Lp), one_chip),
+        lambda i, w, y: ccm_lookup_pallas(i, w, y, interpret=False, **kw),
+        _spec(tables, one_chip, jnp.int32),
+        _spec(tables, one_chip),
+        _spec((B, Lp), one_chip),
     )
 
 
