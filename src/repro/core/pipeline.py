@@ -342,11 +342,12 @@ def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
     return np.concatenate([a, pad], axis=0)
 
 
-def _dispatch(unit: dict, row0: int, fn, *args):
-    """``fn(*args)``, a jitted call, under a ``phase2/dispatch`` span.  While
+def _dispatch(unit: dict, row0: int, fn, *args, stage: str = "phase2",
+              **attrs):
+    """``fn(*args)``, a jitted call, under a ``<stage>/dispatch`` span.  While
     the unit's first chunk runs, its time adds to the unit's
     ``first_dispatch_s``: trace, lower and compile, or cache load."""
-    with telemetry.span("phase2", "dispatch", row0=row0):
+    with telemetry.span(stage, "dispatch", row0=row0, **attrs):
         t0 = _perf()
         out = fn(*args)
         if unit["chunks"] == 0:

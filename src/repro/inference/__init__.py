@@ -11,6 +11,7 @@ from repro.inference.convergence import (
 from repro.inference.pipeline import (
     SignificanceChunkRunner,
     finalize_significance,
+    run_sig_chunks,
     run_significance,
 )
 from repro.inference.significance import (
@@ -44,6 +45,7 @@ __all__ = [
     "convergence_stats",
     "phase_randomized",
     "random_shuffle",
+    "run_sig_chunks",
     "run_significance",
     "subsample_permutation",
     "surrogate_futures",

@@ -29,6 +29,7 @@ artifact is missing.
 from __future__ import annotations
 
 import functools
+from time import perf_counter as _perf
 from typing import Optional
 
 import jax
@@ -39,6 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import ccm
 from repro.core.pipeline import (
+    _dispatch,
     _flat,
     _pad_rows,
     default_mesh,
@@ -130,6 +132,63 @@ def make_null_tile_fn(mesh, cfg: EDMConfig, m: int):
     return for_plan
 
 
+# ------------------------------------------------------------- null tile
+#: share of the device's free memory the sig stage's tile may plan for;
+#: the rest is left to the allocator's fragmentation and XLA's scratch.
+HBM_PLAN_SHARE = 0.8
+
+
+def sig_tile_bytes(t: int, *, m: int, L: int, Lp: int, rows: int, k: int,
+                   n_buckets: int, n_sizes: int, cfg: EDMConfig) -> int:
+    """Device bytes one (row-chunk x column-tile) step of the sig stage
+    holds at its peak, for a tile of ``t`` targets on one device.
+
+    Per target: its m surrogate futures (m x Lp float32), held by up to
+    ``stream_depth`` dispatched tiles and copied once more where the
+    lookup pads a bucket segment to whole ``target_block`` blocks; the
+    FFT's working set for its m surrogates (random bits and phases, the
+    complex spectrum and its rotation, the irfft output, nf = L // 2 + 1
+    bins); the raw series uploaded and the convergence stage's futures;
+    the observed, null and curve blocks of the chunk's ``rows``.  Per
+    tile: the chunk's kNN tables (full library and every library size)
+    and three (rows, target_block, Lp) prediction blocks of the lookups
+    and Pearson's centred copies.
+    """
+    f32, c64 = 4, 8
+    nf = L // 2 + 1
+    surr = m * Lp * f32
+    fft = m * (2 * nf * f32 + 2 * nf * c64 + L * f32)
+    per_target = ((cfg.stream_depth + 1) * surr + fft + (L + Lp) * f32
+                  + rows * (2 * m + 1 + n_sizes) * f32)
+    tables = rows * (1 + n_sizes) * n_buckets * Lp * k * (4 + 4)
+    preds = 3 * rows * cfg.target_block * Lp * f32
+    return t * per_target + tables + preds
+
+
+def sig_null_tile(budget: int | None, cap: int, **shape) -> int:
+    """The largest tile (<= ``cap``) whose :func:`sig_tile_bytes` fit in
+    ``budget`` bytes; ``cap`` where no budget is known (a backend
+    without memory counters), at least 1."""
+    if budget is None:
+        return cap
+    fixed = sig_tile_bytes(0, **shape)
+    per_target = sig_tile_bytes(1, **shape) - fixed
+    return int(max(1, min(cap, (budget - fixed) // per_target)))
+
+
+def _device_budget(mesh) -> int | None:
+    """Bytes the sig stage may plan for on the fullest device of
+    ``mesh``: :data:`HBM_PLAN_SHARE` of ``bytes_limit`` less what is in
+    use there now; None where the backend gives no ``bytes_limit``."""
+    free = []
+    for d in mesh.devices.flat:
+        stats = d.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return None
+        free.append(int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0)))
+    return int(HBM_PLAN_SHARE * min(free))
+
+
 # ----------------------------------------------------- chunk-level compute
 class SignificanceChunkRunner:
     """Compiled per-chunk significance compute — convergence tables and
@@ -144,10 +203,18 @@ class SignificanceChunkRunner:
     keys from sig.seed (per-target fold_in — independent of chunk/tile
     geometry).  ``run`` then computes any subset of row chunks and
     drains blocks through the caller's sink.
+
+    The column tile ``T`` of both stages comes from device memory and
+    shapes (:func:`sig_null_tile`): the largest tile whose surrogate
+    batch, FFT working set and lookups fit the device's free memory,
+    capped by ``cfg.target_tile`` when set and by N.  Off a chip (no
+    ``bytes_limit``) it is ``cfg.target_tile or N``.  Values do not
+    depend on it.
     """
 
     def __init__(self, ts: np.ndarray, optE: np.ndarray, cfg: EDMConfig,
                  sig: SignificanceConfig, mesh=None):
+        t0 = _perf()
         if mesh is None:
             mesh = default_mesh()
         self.mesh, self.cfg, self.sig = mesh, cfg, sig
@@ -164,16 +231,25 @@ class SignificanceChunkRunner:
             )
         self.m = sig.n_surrogates
         self.chunk = mesh.size * cfg.lib_block
-        self.T = cfg.target_tile or N
         self.ts = ts
         optE = np.asarray(optE, np.int32)
         self.plan, self.order = ccm.make_bucket_plan(optE)
-        self.tile_plans = ccm.make_tile_plans(self.plan, self.T)
         self.ts_fut = np.asarray(ccm.all_futures(jnp.asarray(ts), cfg))
 
         key = jax.random.PRNGKey(sig.seed)
         perm_key, self.surr_key = jax.random.split(key)
         self.col_ids = convergence.subsample_permutation(perm_key, Lp)
+        # Set-up the unit span reports: bucket plan, futures, permutation.
+        self._prep_s = _perf() - t0
+
+        self.T = sig_null_tile(
+            _device_budget(mesh), cfg.target_tile or N, m=self.m, L=L, Lp=Lp,
+            rows=cfg.lib_block, k=ccm._bucket_k(cfg, self.plan),
+            n_buckets=len(self.plan.buckets),
+            n_sizes=len(sig.lib_sizes), cfg=cfg,
+        )
+        self.tile_plans = ccm.make_tile_plans(self.plan, self.T)
+        self.surrogate_bytes = self.T * self.m * Lp * 4
 
         self.conv_tables_fn = self.conv_tile_for = None
         self.full_tables_fn = self.null_tile_for = None
@@ -195,10 +271,30 @@ class SignificanceChunkRunner:
         rho: the observed causal map (memmap fine; only read when the
         null stage is active).  on_chunk(row0) fires before each chunk's
         dispatch — fleet workers renew their unit lease there.
+
+        The call is one ``sig/unit`` span: ``prep_s`` (the runner's
+        set-up, reported by the first call only), ``first_dispatch_s``
+        (the first chunk's jitted calls), ``null_tile``,
+        ``surrogate_bytes`` (one tile's surrogate batch), ``chunks`` and
+        ``rows``.
         """
+        prep_s, self._prep_s = self._prep_s, 0.0
+        cache0 = telemetry.compile_cache_entries()
+        with telemetry.span("sig", "unit") as unit:
+            unit.update(prep_s=prep_s, first_dispatch_s=0.0,
+                        null_tile=self.T,
+                        surrogate_bytes=self.surrogate_bytes, chunks=0,
+                        rows=0)
+            self._run(plan_chunks, rho, drain, on_chunk, unit)
+        telemetry.emit_compile_cache("sig", cache0)
+
+    def _run(self, plan_chunks, rho, drain, on_chunk, unit) -> None:
         N, T, m, sig, cfg = self.N, self.T, self.m, self.sig, self.cfg
         order, ts, ts_fut = self.order, self.ts, self.ts_fut
-        cache0 = telemetry.compile_cache_entries()
+
+        def dispatch(row0, what, fn, *args):
+            return _dispatch(unit, row0, fn, *args, stage="sig", what=what)
+
         with ChunkStreamer(drain, depth=cfg.stream_depth,
                            stage="sig") as streamer:
             for row0, valid in plan_chunks:
@@ -219,42 +315,72 @@ class SignificanceChunkRunner:
                         if self.do_null else None
                     )
                     if self.do_conv:
-                        cidx, cw = self.conv_tables_fn(rows_j, self.col_ids)
+                        cidx, cw = dispatch(row0, "conv_tables",
+                                            self.conv_tables_fn, rows_j,
+                                            self.col_ids)
                     if self.do_null:
-                        fidx, fw = self.full_tables_fn(rows_j)
+                        fidx, fw = dispatch(row0, "full_tables",
+                                            self.full_tables_fn, rows_j)
                     for c0, seg_plan in self.tile_plans:
-                        c1 = min(c0 + T, N)
-                        orig = order[c0:c1]
+                        orig = order[c0 : min(c0 + T, N)]
+                        # Every program of the tile is dispatched before
+                        # the streamer drains the previous tile's blocks:
+                        # the device then has this tile's work queued
+                        # while the host waits, writes and gathers.
+                        blocks = []
                         if self.do_conv:
-                            fut_tile = jnp.asarray(ts_fut[orig])
-                            streamer.submit(
+                            blocks.append((
                                 ("conv", row0, c0, valid),
-                                self.conv_tile_for(seg_plan)(
-                                    cidx, cw, fut_tile
-                                ),
-                            )
+                                dispatch(row0, "conv_tile",
+                                         self.conv_tile_for(seg_plan), cidx,
+                                         cw, jnp.asarray(ts_fut[orig])),
+                            ))
                         if self.do_null:
                             # Regenerated per (chunk, tile) like
                             # _phase2_tiled's fut_tile upload: keeping every
                             # tile's (t*m, Lp) surrogate batch resident would
-                            # defeat the tiling at scale, and the per-tile
-                            # FFT is dominated by the m x lookup work the
-                            # tile triggers anyway.
-                            fut_surr = surrogates.surrogate_futures(
+                            # defeat the tiling at scale.  The batch is let
+                            # go once dispatched, so it is freed as its
+                            # null call finishes.
+                            fut_surr = dispatch(
+                                row0, "surrogates",
+                                surrogates.surrogate_futures,
                                 self.surr_key, jnp.asarray(ts[orig]),
                                 jnp.asarray(orig.astype(np.int32)),
-                                n=m, kind=sig.surrogate, cfg=cfg,
+                                m, sig.surrogate, cfg,
                             )
-                            rho_obs = jnp.asarray(
-                                _pad_rows(rho_chunk[:, orig], self.chunk)
-                            )
-                            streamer.submit(
+                            blocks.append((
                                 ("pval", row0, c0, valid),
-                                self.null_tile_for(seg_plan)(
-                                    fidx, fw, fut_surr, rho_obs
-                                ),
-                            )
-        telemetry.emit_compile_cache("sig", cache0)
+                                dispatch(row0, "null_tile",
+                                         self.null_tile_for(seg_plan), fidx,
+                                         fw, fut_surr, jnp.asarray(_pad_rows(
+                                             rho_chunk[:, orig], self.chunk))),
+                            ))
+                            del fut_surr
+                        for tag, block in blocks:
+                            streamer.submit(tag, block)
+                unit["chunks"] += 1
+                unit["rows"] += valid
+
+
+def run_sig_chunks(runner: SignificanceChunkRunner, chunk_plan, rho,
+                   conv_w: Optional[TileWriter], trend_w: Optional[TileWriter],
+                   pv_w: Optional[TileWriter], on_chunk=None) -> None:
+    """The significance stage over an EXPLICIT (row0, nrows) chunk plan —
+    the claimable sig work unit of a fleet worker (DESIGN.md SS10), as
+    :func:`repro.core.pipeline.run_phase2_chunks` is phase 2's.
+
+    Blocks drain through :func:`make_store_drain` into the stage's
+    writers (None for a stage the runner skips), whose manifests are
+    committed once the chunks are done.  One ``sig/unit`` span
+    (:meth:`SignificanceChunkRunner.run`).
+    """
+    writers = [w for w in (conv_w, trend_w, pv_w) if w is not None]
+    runner.run(chunk_plan, rho,
+               make_store_drain(runner.N, conv_w, trend_w, pv_w),
+               on_chunk=on_chunk)
+    for w in writers:
+        w.commit()
 
 
 # ------------------------------------------------------------------- driver

@@ -66,6 +66,12 @@ def phase_randomized(key: jax.Array, x: jax.Array, n: int) -> jax.Array:
 
 _GENERATORS = {"shuffle": random_shuffle, "phase": phase_randomized}
 
+#: The module :func:`surrogate_futures` compiles to, and the name the
+#: device trace's ``XLA Modules`` line gives each of its runs.  On the TPU
+#: its FFT lowers to matrix-unit convolutions, whose operations carry no
+#: name of their own, so the module is what names surrogate generation.
+TRACE_NAME = "jit_surrogate_futures"
+
 
 @functools.partial(jax.jit, static_argnames=("n", "kind", "cfg"))
 def surrogate_futures(

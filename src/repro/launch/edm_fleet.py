@@ -362,7 +362,7 @@ class FleetWorker:
             _check_resume_config,
             _writer,
             finalize_significance,
-            make_store_drain,
+            run_sig_chunks,
         )
 
         sig = self.sig
@@ -380,14 +380,12 @@ class FleetWorker:
             pv_w = _writer(self.out, "pvals", self.N, runner.order,
                            writer_id=self.worker_id)
         writers = [w for w in (conv_w, trend_w, pv_w) if w is not None]
-        drain = make_store_drain(self.N, conv_w, trend_w, pv_w)
 
         def compute(unit):
             self._log(f"sig rows {unit.row0}..{unit.row0 + unit.nrows}")
-            runner.run(_sub_chunks(unit, self.chunk), rho, drain,
-                       on_chunk=lambda row0: self._renew_chunk(unit))
-            for w in writers:
-                w.commit()
+            run_sig_chunks(runner, _sub_chunks(unit, self.chunk), rho,
+                           conv_w, trend_w, pv_w,
+                           on_chunk=lambda row0: self._renew_chunk(unit))
 
         # AND-of-coverages snapshot once per stage entry (SS9 resume
         # semantics: a chunk counts only when EVERY artifact has it).

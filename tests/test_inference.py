@@ -337,3 +337,139 @@ def test_significance_seed_reproducibility(sig_system):
     np.testing.assert_array_equal(outs[0].pvals, outs[1].pvals)
     np.testing.assert_array_equal(np.asarray(outs[0].drho), outs[1].drho)
     assert not np.array_equal(outs[0].pvals, outs[2].pvals)
+
+
+# ------------------------------------ the sig unit against a plain reference
+def _plain():
+    """The benchmark's plain significance reference (benchmarks/chip),
+    which imports nothing of the program."""
+    import pathlib
+    import sys
+
+    here = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(here) not in sys.path:
+        sys.path.insert(0, str(here))
+    import reference
+    import reference_sig
+
+    return reference, reference_sig
+
+
+SIG_ROWS = 16
+
+
+@pytest.fixture(scope="module")
+def sig_case():
+    """48 seeded random series x 300 steps, three optE buckets, and the
+    observed rho of the first SIG_ROWS library rows."""
+    from repro.core import ccm
+    from repro.core.pipeline import default_mesh, run_phase2_chunks
+    from repro.data.synthetic import dummy_brain
+
+    N = 48
+    ts = dummy_brain(N, 300, seed=11)
+    optE = np.random.default_rng(4).choice([2, 3, 5], N).astype(np.int32)
+    cfg = EDMConfig(E_max=6, lib_block=8)
+    fut = np.asarray(ccm.all_futures(jnp.asarray(ts), cfg))
+    rho = np.zeros((SIG_ROWS, N), np.float32)
+    run_phase2_chunks(ts, fut, optE, cfg, default_mesh(),
+                      [(0, 8), (8, 8)], rho=rho)
+    return ts, optE, rho
+
+
+def _sig_maps(tmp_path, case, engine, monkeypatch=None, tile=None):
+    """(drho, trend, pvals) of rows [0, SIG_ROWS) through the sig unit
+    entry, m 9, lib_sizes (40, 120, Lp); ``tile`` forces the null tile by
+    a device budget that fits it and no more."""
+    from repro.core.pipeline import default_mesh
+    from repro.data.store import TileWriter
+    from repro.inference import SignificanceConfig
+    from repro.inference import pipeline as ip
+
+    ts, optE, rho = case
+    N = ts.shape[0]
+    cfg = EDMConfig(E_max=6, lib_block=8, engine=engine)
+    Lp = cfg.n_points(ts.shape[1])
+    sig = SignificanceConfig(lib_sizes=(40, 120, Lp), n_surrogates=9, seed=7)
+    if tile is not None:
+        budget = ip.sig_tile_bytes(
+            tile, m=9, L=ts.shape[1], Lp=Lp, rows=8, k=6, n_buckets=3,
+            n_sizes=3, cfg=cfg)
+        monkeypatch.setattr(ip, "_device_budget", lambda mesh: budget)
+    runner = ip.SignificanceChunkRunner(ts, optE, cfg, sig, default_mesh())
+    assert runner.T == (tile or N)
+    ws = []
+    for name in ("rho_conv", "rho_trend", "pvals"):
+        w = TileWriter(tmp_path / name, SIG_ROWS, N, stage="sig")
+        w.ensure_col_order(runner.order)
+        ws.append(w)
+    ip.run_sig_chunks(runner, [(0, 8), (8, 8)], rho, *ws)
+    return [w.assemble() for w in ws]
+
+
+@pytest.mark.parametrize("engine", ["reference", "pallas-interpret"])
+def test_sig_unit_matches_plain_reference(tmp_path, sig_case, engine):
+    reference, reference_sig = _plain()
+    ts, optE, _ = sig_case
+    drho, trend, pv = _sig_maps(tmp_path, sig_case, engine)
+    m, E_max, Lp = 9, 6, 294
+    kw = dict(E_max=E_max, tau=1, Tp=1, exclude_self=True)
+    lib = ts[:SIG_ROWS]
+    fut = reference.futures(jnp.asarray(ts), E_max, 1, 1)
+    rho_ref = reference.rho_rows(lib, fut, optE, **kw)
+    surr = reference_sig.surrogates(7, jnp.asarray(ts), np.arange(48), m)
+    null = reference.rho_rows(
+        lib, reference.futures(surr.reshape(-1, 300), E_max, 1, 1),
+        np.repeat(optE, m), **kw).reshape(SIG_ROWS, 48, m)
+    p_ref = reference_sig.pvalues(rho_ref, null)
+    curves = reference_sig.curves(lib, fut, optE,
+                                  reference_sig.permutation(7, Lp),
+                                  (40, 120, Lp), **kw)
+    drho_ref, trend_ref, gaps = reference_sig.convergence(curves)
+
+    tie = np.abs(null - rho_ref[..., None]).min(axis=-1) < 1e-4
+    assert tie.sum() < 0.1 * tie.size
+    # p = j / (m + 1): compare the exceedance counts j
+    np.testing.assert_array_equal(np.rint(pv * (m + 1))[~tie],
+                                  np.rint(p_ref * (m + 1))[~tie])
+    assert gaps.min() > 0  # no near-tie in the curves here
+    np.testing.assert_array_equal(trend, trend_ref)
+    np.testing.assert_allclose(drho, drho_ref, atol=1e-5)
+    assert len(np.unique(p_ref)) > m // 2  # the null is not degenerate
+
+
+def test_sig_values_do_not_depend_on_the_null_tile(tmp_path, sig_case,
+                                                   monkeypatch):
+    base = _sig_maps(tmp_path / "untiled", sig_case, "reference")
+    tiled = _sig_maps(tmp_path / "tiled", sig_case, "reference",
+                      monkeypatch, tile=7)
+    for name, a, b in zip(("drho", "trend", "pvals"), base, tiled):
+        if name == "drho":
+            np.testing.assert_allclose(a, b, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_null_tile_rule_at_fish1_on_one_chip():
+    """Fish1_Normo (53,053 x 1,450), m 99, lib_sizes (100, 400, 1430) on
+    a 16 GB chip: the untiled surrogate batch does not fit, the chosen
+    tile does, and is a few thousand targets."""
+    from repro.inference.pipeline import (
+        HBM_PLAN_SHARE, sig_null_tile, sig_tile_bytes,
+    )
+
+    N, L = 53_053, 1_450
+    cfg = EDMConfig(E_max=20)
+    shape = dict(m=99, L=L, Lp=cfg.n_points(L), rows=cfg.lib_block, k=10,
+                 n_buckets=7, n_sizes=3, cfg=cfg)
+    budget = int(HBM_PLAN_SHARE * 16e9)
+    T = sig_null_tile(budget, N, **shape)
+    assert sig_tile_bytes(T, **shape) <= budget
+    assert sig_tile_bytes(T + 1, **shape) > budget
+    assert sig_tile_bytes(N, **shape) > 16e9
+    assert 1_000 < T < 10_000 and 5 <= -(-N // T) <= 30
+    # the futures alone of the untiled batch: 30.0 GB
+    assert N * 99 * 1_430 * 4 == pytest.approx(30.0e9, rel=0.01)
+    assert sig_null_tile(budget, 2_000, **shape) == 2_000  # target_tile cap
+    assert sig_null_tile(None, N, **shape) == N  # no counters: untiled
+    assert sig_null_tile(1, N, **shape) == 1

@@ -539,6 +539,117 @@ def test_phase2_without_sink_records_nothing_and_writes_the_same(
         r["kind"] == "counter" for r in mem.records)
 
 
+# --------------------------------------------------------- sig spans
+def _sig_unit(tmp_path, sink, budget=None, monkeypatch=None):
+    """A tiny significance unit (both stages) through ``run_sig_chunks``
+    and the three TileWriters, its last chunk partial; returns (plan,
+    written bytes, runner)."""
+    from repro.core import EDMConfig
+    from repro.core.pipeline import default_mesh
+    from repro.data.store import TileWriter
+    from repro.data.synthetic import dummy_brain
+    from repro.inference import pipeline as ip
+    from repro.inference import SignificanceConfig
+
+    N, rows = 40, 7
+    ts = dummy_brain(N, 120, seed=8)
+    cfg = EDMConfig(E_max=4, lib_block=2, stream_depth=2)
+    sig = SignificanceConfig(lib_sizes=(30, 60, 116), n_surrogates=5,
+                             seed=4)
+    optE = (np.arange(N) % 3 + 2).astype(np.int32)
+    rho = np.random.default_rng(0).uniform(-0.2, 0.6, (N, N)).astype(
+        np.float32)
+    if budget is not None:
+        monkeypatch.setattr(ip, "_device_budget", lambda mesh: budget)
+    mesh = default_mesh()
+    if sink is not None:
+        telemetry.configure(sink)
+    runner = ip.SignificanceChunkRunner(ts, optE, cfg, sig, mesh)
+    writers = []
+    for name in ("rho_conv", "rho_trend", "pvals"):
+        w = TileWriter(tmp_path / name, N, stage="sig")
+        w.ensure_col_order(runner.order)
+        writers.append(w)
+    plan = [(r, min(runner.chunk, rows - r))
+            for r in range(0, rows, runner.chunk)]
+    ip.run_sig_chunks(runner, plan, rho, *writers)
+    telemetry.configure()
+    written = b"".join(f.read_bytes() for w in writers
+                       for f in sorted(w.dir.glob("tile_*.npy")))
+    return plan, written, runner
+
+
+def test_sig_unit_and_dispatch_nest_under_their_parents(tmp_path,
+                                                        monkeypatch):
+    from repro.core import EDMConfig
+    from repro.inference import pipeline as ip
+
+    mem = telemetry.MemorySink()
+    # a budget that leaves room for 16 of the 40 targets: three tiles
+    budget = ip.sig_tile_bytes(
+        16, m=5, L=120, Lp=116, rows=2, k=5, n_buckets=3, n_sizes=3,
+        cfg=EDMConfig(E_max=4, lib_block=2, stream_depth=2))
+    plan, _, runner = _sig_unit(tmp_path, mem, budget, monkeypatch)
+    assert runner.T == 16 and len(runner.tile_plans) == 3
+    spans = [r for r in mem.records if r["kind"] == "span"
+             and r["stage"] == "sig"]
+    for r in spans:
+        assert telemetry.validate(r) == [], r
+    (unit,) = [r for r in spans if r["name"] == "unit"]
+    a = unit["attrs"]
+    assert a["null_tile"] == 16
+    assert a["surrogate_bytes"] == 16 * 5 * 116 * 4
+    assert a["chunks"] == len(plan) and a["rows"] == 7
+    assert a["prep_s"] > 0 and a["first_dispatch_s"] > 0
+    # everything but the writers' closing manifest commits
+    inner = [r for r in spans if r is not unit]
+    assert inner[-1]["name"] == "manifest_commit"
+    assert all(_within(r, unit) for r in inner[:-3])
+    chunks = [r for r in spans if r["name"] == "chunk"]
+    dispatches = [r for r in spans if r["name"] == "dispatch"]
+    # per chunk: both tables, then per tile the conv call, the
+    # surrogates and the null call
+    per_chunk = ["conv_tables", "full_tables"] + [
+        "conv_tile", "surrogates", "null_tile"] * 3
+    assert len(chunks) == len(plan)
+    assert [d["attrs"]["what"] for d in dispatches] == per_chunk * len(plan)
+    for i, c in enumerate(chunks):
+        for d in dispatches[i * len(per_chunk):(i + 1) * len(per_chunk)]:
+            assert d["attrs"]["row0"] == c["attrs"]["row0"]
+            assert _within(d, c)
+    first = sum(d["dur_s"] for d in dispatches[:len(per_chunk)])
+    assert a["first_dispatch_s"] == pytest.approx(first, abs=1e-3)
+
+
+def test_sig_runner_reports_its_prep_once():
+    from repro.core import EDMConfig
+    from repro.data.synthetic import dummy_brain
+    from repro.inference import SignificanceChunkRunner, SignificanceConfig
+
+    ts = dummy_brain(12, 80, seed=1)
+    cfg = EDMConfig(E_max=3, lib_block=4)
+    runner = SignificanceChunkRunner(
+        ts, np.full(12, 2, np.int32), cfg,
+        SignificanceConfig(lib_sizes=(), n_surrogates=3, seed=0))
+    mem = telemetry.MemorySink()
+    telemetry.configure(mem)
+    rho = np.zeros((12, 12), np.float32)
+    for _ in range(2):
+        runner.run([(0, 4)], rho, lambda tag, block: None)
+    telemetry.configure()
+    units = [r["attrs"] for r in mem.records if r["name"] == "unit"]
+    assert units[0]["prep_s"] > 0 and units[1]["prep_s"] == 0.0
+    assert [u["null_tile"] for u in units] == [12, 12]  # no budget on CPU
+
+
+def test_sig_without_sink_records_nothing_and_writes_the_same(tmp_path):
+    seq = telemetry._seq
+    _, off, _ = _sig_unit(tmp_path / "off", None)
+    assert telemetry._seq == seq
+    _, on, _ = _sig_unit(tmp_path / "on", telemetry.MemorySink())
+    assert off == on and len(off) > 0
+
+
 def test_span_lands_in_the_profiler_host_plane_on_the_trace_clock(
         tmp_path):
     """A span under a sink is also an event of the profiler's host plane;

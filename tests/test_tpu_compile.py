@@ -170,3 +170,59 @@ def test_bucketed_phase2_chunk_fits_v5e_at_fish1_width(topo, monkeypatch):
     total = (m.temp_size_in_bytes + m.argument_size_in_bytes
              + m.output_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB"
+
+
+def test_sig_null_tile_keeps_surrogates_named_and_fits_v5e(topo,
+                                                          monkeypatch):
+    """The significance stage's tile step at Fish1_Normo width, m 99, at
+    the null tile its memory rule picks for a 16 GB chip: surrogate
+    generation compiles to its own module under its trace name, the
+    null call that consumes the batch holds no FFT, and the bytes of the
+    two programs stay inside what the rule planned for."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import EDMConfig, ccm
+    from repro.engine.pallas import PallasEngine
+    from repro.inference import pipeline as ip
+    from repro.inference import surrogates
+
+    monkeypatch.setattr(PallasEngine, "_interpret", lambda self: False)
+    mesh = Mesh(np.array(topo.devices[:1]), ("workers",))
+    cfg = EDMConfig(E_max=E_MAX, engine="pallas-compiled")
+    m, Lp = 99, LP_FISH1
+    optE = (np.arange(FISH1_N) % 7 + 3).astype(np.int32)  # E 3..9
+    plan, _ = ccm.make_bucket_plan(optE)
+    shape = dict(m=m, L=FISH1_L, Lp=Lp, rows=cfg.lib_block,
+                 k=ccm._bucket_k(cfg, plan), n_buckets=len(plan.buckets),
+                 n_sizes=3, cfg=cfg)
+    budget = int(ip.HBM_PLAN_SHARE * HBM_BYTES)
+    T = ip.sig_null_tile(budget, FISH1_N, **shape)
+    assert 1_000 < T < 10_000
+
+    repl = NamedSharding(mesh, P(None, None))
+    surr = surrogates.surrogate_futures.lower(
+        _spec((2,), NamedSharding(mesh, P(None)), jnp.uint32),
+        _spec((T, FISH1_L), repl),
+        _spec((T,), NamedSharding(mesh, P(None)), jnp.int32),
+        m, "phase", cfg).compile()
+    text = surr.as_text()
+    assert text.startswith(f"HloModule {surrogates.TRACE_NAME},")
+    assert " fft(" not in text  # lowered to convolutions on the chip
+
+    seg_plan = ccm.make_tile_plans(plan, T)[0][1]
+    tables = NamedSharding(mesh, P("workers", None, None, None))
+    nb, kb = shape["n_buckets"], shape["k"]
+    null = ip.make_null_tile_fn(mesh, cfg, m)(seg_plan).lower(
+        _spec((cfg.lib_block, nb, Lp, kb), tables, jnp.int32),
+        _spec((cfg.lib_block, nb, Lp, kb), tables),
+        _spec((T * m, Lp), repl),
+        _spec((cfg.lib_block, T), NamedSharding(mesh, P("workers", None))),
+    ).compile()
+    assert "tpu_custom_call" in null.as_text()
+    assert "surrogate" not in null.as_text().split("\n", 1)[0]
+    s, n = surr.memory_analysis(), null.memory_analysis()
+    # a batch made while the previous tile's null call still holds its
+    # own, then the null call's working set
+    peak = max(s.temp_size_in_bytes + s.output_size_in_bytes,
+               n.temp_size_in_bytes) + n.argument_size_in_bytes
+    assert peak <= ip.sig_tile_bytes(T, **shape) <= budget
