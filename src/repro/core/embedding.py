@@ -44,7 +44,11 @@ def delay_embed(x: jax.Array, E: int, tau: int, Tp: int = 0) -> jax.Array:
     return x[idx]
 
 
+def future_start(E_max: int, tau: int, Tp: int) -> int:
+    """Series index of point 0's future, p(0) + Tp."""
+    return (E_max - 1) * tau + Tp
+
+
 def future_values(x: jax.Array, E_max: int, tau: int, Tp: int, Lp: int) -> jax.Array:
     """fut[t] = x[p(t) + Tp]: the value a simplex forecast of point t targets."""
-    offset = (E_max - 1) * tau
-    return x[offset + Tp + jnp.arange(Lp)]
+    return x[future_start(E_max, tau, Tp) + jnp.arange(Lp)]

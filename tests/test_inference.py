@@ -155,6 +155,69 @@ def test_surrogate_futures_deterministic_per_series_id():
     assert not np.array_equal(solo, other)
 
 
+def _phase_surrogates_f64(key, x, n):
+    """Float64 NumPy phase surrogates from the keys and phases the
+    program draws: np.fft.irfft of the phase-randomised spectrum."""
+    L = x.shape[-1]
+    nf = L // 2 + 1
+    phases = np.stack([
+        np.asarray(jax.random.uniform(k, (nf,), minval=0.0,
+                                      maxval=2.0 * jnp.pi))
+        for k in jax.random.split(key, n)
+    ]).astype(np.float64)
+    X = np.fft.rfft(x.astype(np.float64))
+    Xs = np.abs(X) * np.exp(1j * phases)
+    Xs[:, 0] = X[0]
+    if L % 2 == 0:
+        Xs[:, -1] = X[-1]
+    return np.fft.irfft(Xs, n=L)
+
+
+@pytest.mark.parametrize("L", [200, 201])  # even L has a Nyquist bin
+def test_phase_surrogates_match_float64_irfft(L):
+    """The real inverse-DFT product matches a float64 irfft of the same
+    spectrum to float32 rounding of the series' scale."""
+    from repro.inference import phase_randomized
+
+    rng = np.random.default_rng(5)
+    x = np.cumsum(rng.standard_normal(L)).astype(np.float32)
+    key = jax.random.PRNGKey(11)
+    s = np.asarray(phase_randomized(key, jnp.asarray(x), 6))
+    assert s.shape == (6, L) and s.dtype == np.float32
+    np.testing.assert_allclose(s, _phase_surrogates_f64(key, x, 6), rtol=0,
+                               atol=1e-5 * np.abs(x).max())
+
+
+@pytest.mark.parametrize("L", [200, 201])
+def test_surrogate_futures_are_future_values_of_phase_surrogates(L):
+    """The batched entry's (t * n, Lp) rows are each target's phase
+    surrogates (drawn under fold_in(key, series id)) at the future
+    positions, whether the product starts there or at sample 0."""
+    from repro.core import future_values
+    from repro.inference import phase_randomized, surrogate_futures
+
+    rng = np.random.default_rng(6)
+    cfg = EDMConfig(E_max=5, tau=2, Tp=1)
+    ts = np.cumsum(rng.standard_normal((3, L)), axis=-1).astype(np.float32)
+    ids = np.asarray([4, 0, 9], np.int32)
+    key = jax.random.PRNGKey(12)
+    Lp = cfg.n_points(L)
+    got = np.asarray(surrogate_futures(key, jnp.asarray(ts), jnp.asarray(ids),
+                                       n=5, kind="phase", cfg=cfg))
+    assert got.shape == (3 * 5, Lp)
+    for i in range(3):
+        k = jax.random.fold_in(key, int(ids[i]))
+        full = phase_randomized(k, jnp.asarray(ts[i]), 5)
+        want = np.asarray(jax.vmap(
+            lambda s: future_values(s, cfg.E_max, cfg.tau, cfg.Tp, Lp))(full))
+        np.testing.assert_allclose(got[5 * i:5 * (i + 1)], want, rtol=0,
+                                   atol=1e-5 * np.abs(ts[i]).max())
+        np.testing.assert_allclose(
+            want, _phase_surrogates_f64(k, ts[i], 5)[
+                :, (cfg.E_max - 1) * cfg.tau + cfg.Tp:][:, :Lp],
+            rtol=0, atol=1e-5 * np.abs(ts[i]).max())
+
+
 # ----------------------------------------------------------------- BH-FDR
 def test_bh_adjust_matches_scipy_oracle():
     sp = pytest.importorskip("scipy.stats")

@@ -226,3 +226,27 @@ def test_sig_null_tile_keeps_surrogates_named_and_fits_v5e(topo,
     peak = max(s.temp_size_in_bytes + s.output_size_in_bytes,
                n.temp_size_in_bytes) + n.argument_size_in_bytes
     assert peak <= ip.sig_tile_bytes(T, **shape) <= budget
+
+
+def test_surrogates_are_one_real_dft_product_at_highest_for_v5e(one_chip):
+    """Phase surrogates at Fish1_Normo width (L 1,450, m 99): the module's
+    flops per surrogate stay within 1.2x of one real inverse-DFT product,
+    2 (2 nf) Lp, so a return to the compiler's three-product complex DFT
+    of the padded spectrum (about 3x) fails; and every product in it
+    runs at highest precision."""
+    from repro.core import EDMConfig
+    from repro.inference import surrogates
+
+    cfg = EDMConfig(E_max=E_MAX)
+    T, m, nf = 256, 99, FISH1_L // 2 + 1
+    compiled = surrogates.surrogate_futures.lower(
+        _spec((2,), one_chip, jnp.uint32), _spec((T, FISH1_L), one_chip),
+        _spec((T,), one_chip, jnp.int32), m, "phase", cfg).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["flops"] / (T * m) <= 1.2 * 2 * (2 * nf) * LP_FISH1
+    products = [line for line in compiled.as_text().splitlines()
+                if re.search(r"\s(convolution|dot)\(", line)]
+    assert products
+    for line in products:
+        assert "operand_precision={highest,highest}" in line, line
