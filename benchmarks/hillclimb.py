@@ -3,7 +3,6 @@ roofline delta vs the recorded baseline.  Results land in
 benchmarks/results/hillclimb/ and the narrative in EXPERIMENTS.md SSPerf.
 
   PYTHONPATH=src python -m benchmarks.hillclimb --target minicpm-prefill --variant chunked_attn
-  PYTHONPATH=src python -m benchmarks.hillclimb --target edm --variant unroll
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
@@ -65,25 +64,6 @@ TARGETS = {
     "grok-train": ("grok-1-314b", "train_4k"),
 }
 
-EDM_VARIANTS = {
-    "baseline": {},
-    "unroll": {"knn_impl": "unroll"},
-    "rebuild": {"knn_impl": "rebuild"},
-    "bf16_dist": {"dist_dtype": "bfloat16"},
-    "unroll+bf16": {"knn_impl": "unroll", "dist_dtype": "bfloat16"},
-    "rebuild+bf16": {"knn_impl": "rebuild", "dist_dtype": "bfloat16"},
-    "lib4": {"lib_block": 4},
-    "unroll+lib4": {"knn_impl": "unroll", "lib_block": 4},
-    "rebuild+lib4": {"knn_impl": "rebuild", "lib_block": 4},
-    "rebuild+lib4+tb4096": {"knn_impl": "rebuild", "lib_block": 4, "target_block": 4096},
-    "unroll+lib2": {"knn_impl": "unroll", "lib_block": 2},
-    "unroll+lib1": {"knn_impl": "unroll", "lib_block": 1},
-    "blocked4+lib4": {"knn_impl": "blocked:4", "lib_block": 4},
-    "blocked5+lib2": {"knn_impl": "blocked:5", "lib_block": 2},
-    "blocked4+lib2": {"knn_impl": "blocked:4", "lib_block": 2},
-}
-
-
 TC_VARIANTS = {"bf16_moments": {"moment_dtype": "bfloat16"}}
 
 
@@ -95,29 +75,21 @@ def main():
     args = ap.parse_args()
     RESULTS.mkdir(parents=True, exist_ok=True)
 
-    if args.target == "edm":
-        from repro.configs.edm_datasets import SUBJECT11
-        from repro.launch.edm_dryrun import lower_edm_cell
+    from repro.configs import get_config
+    from repro.launch.dryrun import lower_cell
 
-        cfg = dataclasses.replace(SUBJECT11.edm, **EDM_VARIANTS[args.variant])
-        res = lower_edm_cell("subject11", multi_pod=args.multi_pod, cfg=cfg)
-        res["variant"] = args.variant
-    else:
-        from repro.configs import get_config
-        from repro.launch.dryrun import lower_cell
+    arch, cell = TARGETS[args.target]
+    parts = args.variant.split("&")
+    cfg_kw, pol_kw = LM_VARIANTS[parts[0]]
+    cfg = dataclasses.replace(get_config(arch), **cfg_kw)
+    if len(parts) > 1:
+        import repro.launch.dryrun as DR
 
-        arch, cell = TARGETS[args.target]
-        parts = args.variant.split("&")
-        cfg_kw, pol_kw = LM_VARIANTS[parts[0]]
-        cfg = dataclasses.replace(get_config(arch), **cfg_kw)
-        if len(parts) > 1:
-            import repro.launch.dryrun as DR
-
-            tc_kw = TC_VARIANTS[parts[1]]
-            orig = DR.train_config_for
-            DR.train_config_for = lambda a: dataclasses.replace(orig(a), **tc_kw)
-        res = lower_cell(arch, cell, multi_pod=args.multi_pod, cfg=cfg,
-                         policy_kw=pol_kw, variant=args.variant)
+        tc_kw = TC_VARIANTS[parts[1]]
+        orig = DR.train_config_for
+        DR.train_config_for = lambda a: dataclasses.replace(orig(a), **tc_kw)
+    res = lower_cell(arch, cell, multi_pod=args.multi_pod, cfg=cfg,
+                     policy_kw=pol_kw, variant=args.variant)
 
     out = RESULTS / f"{args.target}__{args.variant}.json"
     out.write_text(json.dumps(res, indent=2))

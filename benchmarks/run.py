@@ -44,7 +44,6 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import (  # noqa: E402
     EDMConfig,
     all_futures,
-    ccm_block,
     ccm_matrix,
     ccm_pair_naive,
     knn_table_single_E,
@@ -253,11 +252,11 @@ def fig9b_knn_impl_variants():
 
 # ------------------------------------------------------- phase-2 engine bench
 def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
-    """Phase-2 wall clock + host memory: seed path (all-E tables, dense
-    host map, synchronous drain) vs optE-bucketed tables + double-buffered
-    streaming (DESIGN.md SS3/SS6) vs the 2D target-tiled decomposition
-    (DESIGN.md SS7: tables once per chunk + column tiles, NO dense host
-    map), through the real pipeline loops including the TileWriter.
+    """Phase-2 wall clock + host memory: untiled optE-bucketed tables +
+    double-buffered streaming (DESIGN.md SS3/SS6) vs the 2D target-tiled
+    decomposition (DESIGN.md SS7: tables once per chunk + column tiles,
+    NO dense host map), through the real pipeline loops including the
+    TileWriter.
     Records engine name, bucket count, tile geometry, and per-variant
     host-allocation peaks (tracemalloc) + process peak RSS to
     BENCH_phase2.json so trajectories stay comparable across backends.
@@ -270,7 +269,6 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
 
     from repro.core import make_bucket_plan, make_tile_plans
     from repro.core.pipeline import (
-        make_ccm_chunk_fn,
         make_ccm_chunk_fn_bucketed,
         make_ccm_tables_fn_bucketed,
         make_ccm_tile_fn_bucketed,
@@ -281,10 +279,9 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
 
     mesh = jax.make_mesh((len(jax.devices()),), ("workers",))
     base = dict(E_max=E_max, engine=engine, lib_block=8)
-    cfg_seed = EDMConfig(**base, bucketed=False, stream_depth=1)
-    cfg_new = EDMConfig(**base, bucketed=True, stream_depth=2)
-    cfg_tiled = EDMConfig(**base, bucketed=True, stream_depth=2, target_tile=tile)
-    chunk = mesh.size * cfg_seed.lib_block
+    cfg_new = EDMConfig(**base, stream_depth=2)
+    cfg_tiled = EDMConfig(**base, stream_depth=2, target_tile=tile)
+    chunk = mesh.size * cfg_new.lib_block
 
     ts = jnp.asarray(dummy_brain(N, L, seed=42))
     _, optE = simplex_batch(ts, cfg_new)
@@ -293,27 +290,29 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
     ts_fut = all_futures(ts, cfg_new)
     ts_np = np.asarray(ts)
 
-    def run_loop(chunk_fn, args_of_rows, unsort, depth, out_dir):
-        # seed-shaped loop: full-width row blocks into a DENSE host map
+    inv = np.argsort(order)
+    ts_fut_sorted = ts_fut[jnp.asarray(order)]  # hoisted, as in the pipeline
+    chunk_fn = make_ccm_chunk_fn_bucketed(mesh, cfg_new, plan)
+
+    def run_loop(out_dir):
+        # untiled loop: full-width row blocks, un-sorted into a DENSE host map
         writer = TileWriter(out_dir, N)
         rho = np.zeros((N, N), np.float32)
 
         def drain(tag, rows_dev):
             row0, valid = tag
-            rows_np = unsort(rows_dev)[:valid]
+            rows_np = rows_dev[:, inv][:valid]
             rho[row0 : row0 + valid] = rows_np
             writer.write_block(row0, rows_np)
 
         t0 = time.perf_counter()
-        with ChunkStreamer(drain, depth=depth) as s:
+        with ChunkStreamer(drain, depth=cfg_new.stream_depth) as s:
             for row0 in range(0, N, chunk):
                 valid = min(chunk, N - row0)
                 rows = _pad_rows(ts_np[row0 : row0 + chunk], chunk)
-                s.submit((row0, valid), chunk_fn(*args_of_rows(rows)))
+                s.submit((row0, valid), chunk_fn(jnp.asarray(rows), ts_fut_sorted))
         return time.perf_counter() - t0, rho
 
-    inv = np.argsort(order)
-    ts_fut_sorted = ts_fut[jnp.asarray(order)]  # hoisted, as in the pipeline
     ts_fut_sorted_np = np.asarray(ts_fut_sorted)
     tile_plans = make_tile_plans(plan, tile)
     tables_fn = make_ccm_tables_fn_bucketed(mesh, cfg_tiled, plan)
@@ -344,30 +343,18 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
         rho = writer.assemble(mmap_path=writer.dir / "causal_map" / "data.npy")
         return time.perf_counter() - t0, rho  # rho is a disk-backed memmap
 
-    variants = {
-        "seed_all_e_sync": (
-            make_ccm_chunk_fn(mesh, cfg_seed),
-            lambda rows: (jnp.asarray(rows), ts_fut, optE),
-            lambda r: r,
-            1,
-        ),
-        "bucketed_double_buffered": (
-            make_ccm_chunk_fn_bucketed(mesh, cfg_new, plan),
-            lambda rows: (jnp.asarray(rows), ts_fut_sorted),
-            lambda r: r[:, inv],
-            2,
-        ),
-    }
     times, rhos, host_peaks = {}, {}, {}
-    for name, (fn, args_of_rows, unsort, depth) in variants.items():
-        # warm the compile cache so we time steady-state phase 2
-        jax.block_until_ready(fn(*args_of_rows(_pad_rows(ts_np[:chunk], chunk))))
-        tracemalloc.start()
-        with tempfile.TemporaryDirectory() as d:
-            times[name], rhos[name] = run_loop(fn, args_of_rows, unsort, depth, d)
-        host_peaks[name] = tracemalloc.get_traced_memory()[1]
-        tracemalloc.stop()
-        row(f"phase2_{name}", times[name], f"N={N};L={L};E_max={E_max}")
+    name = "bucketed_double_buffered"
+    # warm the compile cache so we time steady-state phase 2
+    jax.block_until_ready(
+        chunk_fn(jnp.asarray(_pad_rows(ts_np[:chunk], chunk)), ts_fut_sorted)
+    )
+    tracemalloc.start()
+    with tempfile.TemporaryDirectory() as d:
+        times[name], rhos[name] = run_loop(d)
+    host_peaks[name] = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
+    row(f"phase2_{name}", times[name], f"N={N};L={L};E_max={E_max}")
 
     # warm the tiled fns (tables + every distinct tile signature)
     idx_w, w_w = tables_fn(jnp.asarray(_pad_rows(ts_np[:chunk], chunk)))
@@ -392,19 +379,14 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
         f"N={N};L={L};tile={tile};n_col_tiles={len(tile_plans)}",
     )
 
-    err = float(
-        np.abs(rhos["seed_all_e_sync"] - rhos["bucketed_double_buffered"]).max()
-    )
     err_tiled = float(
         np.abs(rhos["bucketed_double_buffered"] - rhos["bucketed_tiled"]).max()
     )
-    speedup = times["seed_all_e_sync"] / times["bucketed_double_buffered"]
     ru_maxrss_kb = int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss)
-    row("phase2_speedup", 0.0, f"speedup={speedup:.2f}x;max_drho={err:.1e}")
     row(
         "phase2_tiled_host_peak", 0.0,
         f"host_peak_MiB={host_peaks['bucketed_tiled'] / 2**20:.1f};"
-        f"dense_MiB={host_peaks['seed_all_e_sync'] / 2**20:.1f};"
+        f"dense_MiB={host_peaks['bucketed_double_buffered'] / 2**20:.1f};"
         f"tiled_drho={err_tiled:.1e}",
     )
 
@@ -421,11 +403,6 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
             "n_tile_signatures": len({sp for _, sp in tile_plans}),
             "chunk_rows": chunk,
         },
-        "seed_path": {
-            "bucketed": False, "stream_depth": 1,
-            "phase2_s": times["seed_all_e_sync"],
-            "host_peak_bytes": host_peaks["seed_all_e_sync"],
-        },
         "new_path": {
             "bucketed": True, "stream_depth": 2,
             "phase2_s": times["bucketed_double_buffered"],
@@ -437,8 +414,6 @@ def phase2_engine_bench(N=128, L=1000, E_max=20, engine="reference", tile=32):
             "host_peak_bytes": host_peaks["bucketed_tiled"],
         },
         "ru_maxrss_kb": ru_maxrss_kb,
-        "speedup": speedup,
-        "max_abs_drho": err,
         "max_abs_drho_tiled": err_tiled,
     }
     _write_bench("BENCH_phase2.json", out)
@@ -939,8 +914,7 @@ BENCHES = {
 GATES: dict[str, tuple[str, list[tuple[str, ...]]]] = {
     "phase2": (
         "BENCH_phase2.json",
-        [("seed_path", "phase2_s"), ("new_path", "phase2_s"),
-         ("tiled_path", "phase2_s")],
+        [("new_path", "phase2_s"), ("tiled_path", "phase2_s")],
     ),
     "knn": (
         "BENCH_knn.json",

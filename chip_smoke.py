@@ -51,7 +51,7 @@ SRC = ROOT / "src"
 OUT = ROOT / "chiprun_out"
 
 SEED = 0
-N_FISH1, L_FISH1 = 53_053, 1_450  # Fish1_Normo, configs/edm_datasets.py
+N_FISH1, L_FISH1 = 53_053, 1_450  # Fish1_Normo, benchmarks/chip/configs/fish1_normo.json
 L_SUBJECT11 = 8_528  # Subject11
 E_MAX = 20
 SHARE = -(-N_FISH1 // 512)  # 104 library rows: one of 512 workers

@@ -3,29 +3,25 @@
 Key idea reproduced from the paper: the kNN table depends only on the
 *library* series, so per library series i we precompute tables once and
 reuse them across all N targets — O(N L^2 E^2 + N^2 L E) vs cppEDM's
-O(N^2 L^2 E).  Two table layouts (DESIGN.md SS3):
-
-  * all-E      — tables for every E in 1..E_max (the paper's shape);
-  * bucketed   — tables only for the DISTINCT optE values present, with
-    targets grouped by bucket so every lookup batch shares one table
-    (contiguous gathers; the layout kernels/ccm_lookup is built for).
-
-Both produce the same causal map; the bucketed layout is the default in
-EDMConfig and cuts table top-k work and footprint by len(buckets)/E_max.
+O(N^2 L^2 E).  Phase 2 has one table layout, the optE-BUCKETED one
+(DESIGN.md SS3): tables only for the DISTINCT optE values present, with
+targets grouped by bucket so every lookup batch shares one table
+(contiguous gathers; the layout kernels/ccm_lookup is built for).  It
+cuts table top-k work and footprint by len(buckets)/E_max against the
+paper's all-E shape, whose oracle is :func:`repro.core.baseline.ccm_naive`.
 
 rho[i, j] = pearson(ts_j_future, cross_map_prediction) — the skill of
 predicting series j from library i's reconstructed manifold; high skill
 means j CCM-causes i (paper SSII-B).
 
 Phase 2 is additionally TILEABLE along the target (column) axis
-(DESIGN.md SS7): table construction (`ccm_row_tables*` / the
-`ccm_block_tables*` wrappers) is split from the lookup
-(`ccm_row_lookup*` / `ccm_block_tile*`) so one kNN table set per
-library chunk is reused across every column tile — tables depend only
-on the library series, so tiling never rebuilds them — and only the
+(DESIGN.md SS7): table construction (`ccm_row_tables_bucketed` /
+`ccm_block_tables_bucketed`) is split from the lookup
+(`ccm_row_lookup_bucketed` / `ccm_block_tile_bucketed`) so one kNN table
+set per library chunk is reused across every column tile — tables depend
+only on the library series, so tiling never rebuilds them — and only the
 live (tile, Lp) slice of the target futures needs to be resident.
-`cfg.target_tile = 0` keeps the single-tile path; both produce
-bit-identical causal maps.
+`cfg.target_tile = 0` keeps the single-tile path.
 
 All device compute routes through the execution engine named by
 cfg.engine (repro.engine; DESIGN.md SS5).  Table construction inside the
@@ -121,23 +117,6 @@ def _bucket_k(cfg: EDMConfig, plan: BucketPlan) -> int:
     return plan.buckets[-1] + 1 if cfg.k_override is None else cfg.k_override
 
 
-def ccm_row_tables(x: jax.Array, cfg: EDMConfig) -> tuple[jax.Array, jax.Array]:
-    """kNN tables + simplex weights for ONE library series, all-E layout.
-
-    x: (L,).  Returns (idx, w), each (E_max, Lp, k_max).  Tables depend
-    only on the library series, so callers reuse them across every target
-    tile of a chunk (DESIGN.md SS7).  The engine streams candidate tiles
-    of the width resolved from cfg.knn_tile_c (DESIGN.md SS8) —
-    identical tables at any width.
-    """
-    eng = engines.get_engine(cfg.engine)
-    Lp = cfg.n_points(x.shape[0])
-    _check_k(cfg.k_max, Lp, cfg, "ccm_row_tables")
-    V = embedding.lag_matrix(x, cfg.E_max, cfg.tau, Lp)
-    idx, sqd = eng.knn_tables(V, V, cfg.k_max, exclude_self=cfg.exclude_self, cfg=cfg)
-    return knn.tables_with_weights(idx, sqd)
-
-
 def ccm_row_tables_bucketed(
     x: jax.Array, cfg: EDMConfig, plan: BucketPlan
 ) -> tuple[jax.Array, jax.Array]:
@@ -154,53 +133,6 @@ def ccm_row_tables_bucketed(
         V, V, kb, buckets=plan.buckets, exclude_self=cfg.exclude_self, cfg=cfg
     )
     return knn.tables_with_weights_bucketed(idx, sqd, plan.buckets)
-
-
-def ccm_row_lookup(
-    idx: jax.Array, w: jax.Array, ts_fut: jax.Array, e_idx: jax.Array,
-    cfg: EDMConfig,
-) -> jax.Array:
-    """rho of a batch of targets against one library's all-E tables.
-
-    idx/w: (E_max, Lp, k) tables from :func:`ccm_row_tables`; ts_fut:
-    (n, Lp) target futures; e_idx: (n,) TABLE INDEX per target (optE - 1).
-    Targets are processed in blocks of cfg.target_block (lax.map) so the
-    (block, Lp) prediction buffer stays bounded at brain scale (N ~ 1e5).
-    """
-    eng = engines.get_engine(cfg.engine)
-    n = ts_fut.shape[0]
-
-    def per_target(y_fut: jax.Array, e: jax.Array) -> jax.Array:
-        # Cross mapping: library neighbours, *target* futures (paper line 10).
-        pred = eng.simplex_forecast(idx[e], w[e], y_fut)
-        return pearson(y_fut, pred)
-
-    tb = min(cfg.target_block, n)
-    if n % tb != 0:  # pad targets to a block multiple
-        pad = tb - n % tb
-        ts_fut = jnp.pad(ts_fut, ((0, pad), (0, 0)))
-        e_idx = jnp.pad(e_idx, (0, pad))
-    blocks = (
-        ts_fut.reshape(-1, tb, ts_fut.shape[1]),
-        e_idx.reshape(-1, tb),
-    )
-    rho = jax.lax.map(
-        lambda be: jax.vmap(per_target)(be[0], be[1]), blocks
-    ).reshape(-1)
-    return rho[:n]
-
-
-def ccm_library_row(
-    x: jax.Array, ts_fut: jax.Array, optE: jax.Array, cfg: EDMConfig
-) -> jax.Array:
-    """Cross-map every target from one library series (all-E table layout).
-
-    x: (L,) library series.  ts_fut: (N, Lp) future values of every target
-    (precomputed once per run).  optE: (N,) optimal E per target.
-    Returns rho row (N,).
-    """
-    idx, w = ccm_row_tables(x, cfg)
-    return ccm_row_lookup(idx, w, ts_fut, optE - 1, cfg)
 
 
 def _rho_for_table(eng, idx, w, seg, cfg: EDMConfig) -> jax.Array:
@@ -293,14 +225,6 @@ def make_tile_plans(
     return plans
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def ccm_block(
-    lib_block: jax.Array, ts_fut: jax.Array, optE: jax.Array, cfg: EDMConfig
-) -> jax.Array:
-    """rho rows for a block of library series: (B, L) -> (B, N)."""
-    return jax.vmap(lambda x: ccm_library_row(x, ts_fut, optE, cfg))(lib_block)
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "plan"))
 def ccm_block_bucketed(
     lib_block: jax.Array, ts_fut_sorted: jax.Array, cfg: EDMConfig, plan: BucketPlan
@@ -312,16 +236,8 @@ def ccm_block_bucketed(
 
 
 # ------------------------------------------------- tiled phase 2 (DESIGN SS7)
-def _block_tables(lib_block: jax.Array, cfg: EDMConfig):
-    return jax.vmap(lambda x: ccm_row_tables(x, cfg))(lib_block)
-
-
 def _block_tables_bucketed(lib_block: jax.Array, cfg: EDMConfig, plan: BucketPlan):
     return jax.vmap(lambda x: ccm_row_tables_bucketed(x, cfg, plan))(lib_block)
-
-
-def _block_tile(idx, w, fut_tile, e_idx, cfg: EDMConfig):
-    return jax.vmap(lambda i, ww: ccm_row_lookup(i, ww, fut_tile, e_idx, cfg))(idx, w)
 
 
 def _block_tile_bucketed(idx, w, fut_tile, cfg: EDMConfig, seg_plan):
@@ -330,26 +246,12 @@ def _block_tile_bucketed(idx, w, fut_tile, cfg: EDMConfig, seg_plan):
     )(idx, w)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def ccm_block_tables(lib_block: jax.Array, cfg: EDMConfig):
-    """All-E tables for a block of library series: (B, L) ->
-    (idx, w) each (B, E_max, Lp, k).  Built ONCE per row chunk and reused
-    by every :func:`ccm_block_tile` call of that chunk."""
-    return _block_tables(lib_block, cfg)
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "plan"))
 def ccm_block_tables_bucketed(lib_block: jax.Array, cfg: EDMConfig, plan: BucketPlan):
     """Bucketed tables for a block: (B, L) -> (idx, w) each
-    (B, len(buckets), Lp, k)."""
+    (B, len(buckets), Lp, k).  Built ONCE per row chunk and reused by
+    every :func:`ccm_block_tile_bucketed` call of that chunk."""
     return _block_tables_bucketed(lib_block, cfg, plan)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def ccm_block_tile(idx, w, fut_tile, e_idx, cfg: EDMConfig):
-    """One (row-chunk x col-tile) rho block, all-E layout: tables (B, ...)
-    + fut_tile (t, Lp) + e_idx (t,) -> rho (B, t)."""
-    return _block_tile(idx, w, fut_tile, e_idx, cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "seg_plan"))
@@ -372,15 +274,12 @@ def all_futures(ts: jax.Array, cfg: EDMConfig) -> jax.Array:
 def ccm_matrix(ts: jax.Array, optE: jax.Array, cfg: EDMConfig) -> jax.Array:
     """Full (N, N) causal map on one device (small problems / tests).
 
-    Dispatches on cfg.bucketed and cfg.target_tile; every combination
-    returns an identical map (the bucket permutation is undone on the
-    columns before returning, tiles are reassembled in column order).
+    Dispatches on cfg.target_tile; the bucket permutation is undone on the
+    columns before returning, and tiles are reassembled in column order.
     """
     ts_fut = all_futures(ts, cfg)
     if cfg.target_tile:
         return _ccm_matrix_tiled(ts, ts_fut, optE, cfg)
-    if not cfg.bucketed:
-        return ccm_block(ts, ts_fut, optE, cfg)
     plan, order = make_bucket_plan(np.asarray(optE))
     order_j = jnp.asarray(order)
     rho_sorted = ccm_block_bucketed(ts, ts_fut[order_j], cfg, plan)
@@ -394,21 +293,7 @@ def _ccm_matrix_tiled(
     """Single-device tiled phase 2: tables once, targets in column tiles."""
     N = ts.shape[0]
     T = cfg.target_tile
-    optE_np = np.asarray(optE)
-    if not cfg.bucketed:
-        idx, w = ccm_block_tables(ts, cfg)
-        e_idx = jnp.asarray(optE_np.astype(np.int32) - 1)
-        cols = [
-            ccm_block_tile(
-                idx, w,
-                jax.lax.slice_in_dim(ts_fut, c0, min(c0 + T, N)),
-                jax.lax.slice_in_dim(e_idx, c0, min(c0 + T, N)),
-                cfg,
-            )
-            for c0 in range(0, N, T)
-        ]
-        return jnp.concatenate(cols, axis=1)
-    plan, order = make_bucket_plan(optE_np)
+    plan, order = make_bucket_plan(np.asarray(optE))
     idx, w = ccm_block_tables_bucketed(ts, cfg, plan)
     ts_fut_sorted = ts_fut[jnp.asarray(order)]
     cols = [

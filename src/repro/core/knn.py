@@ -591,8 +591,8 @@ def knn_tables_all_E_streaming(
     """All-E streaming tables — identical (idx, sq_dists) to the dense
     oracle :func:`knn_tables_dense` (cumulative impls), (E_max, Lq, k_max)
     each, built without ever materializing the (Lq, Lc) distance matrix
-    (DESIGN.md SS8).  THE engine selection path for phase 1 / unbucketed
-    phase 2."""
+    (DESIGN.md SS8).  THE engine selection path for phase 1 and the
+    library-sharded builds."""
     E_max, Lq = Vq.shape
     unsharded = col_hi is None and isinstance(col_offset, int) and col_offset == 0
     if exclude_self and unsharded and Lq != Vc.shape[1]:

@@ -8,9 +8,9 @@ as one worker axis, matching the paper's 512 flat workers):
     gathered to host (N int32 — the paper's single broadcast);
   phase 2 (CCM): double-buffered loop over row CHUNKS (chunk = workers x
     lib_block); each chunk is one or more jit'd shard_map calls with zero
-    internal collectives.  With cfg.bucketed (default) targets are
-    grouped by distinct optE so each chunk builds kNN tables only for
-    the bucket set (DESIGN.md SS3).  With cfg.target_tile > 0 phase 2
+    internal collectives.  Targets are grouped by distinct optE so each
+    chunk builds kNN tables only for the bucket set (DESIGN.md SS3).
+    With cfg.target_tile > 0 phase 2
     gains a SECOND tiling dimension (DESIGN.md SS7): kNN tables are
     built once per chunk (they depend only on the library rows) and the
     targets stream through in column tiles — only the live (tile, Lp)
@@ -81,25 +81,6 @@ def make_simplex_fn(mesh, cfg: EDMConfig):
     )
 
 
-def make_ccm_chunk_fn(mesh, cfg: EDMConfig):
-    """(lib_rows (chunk, L) sharded, ts_fut (N, Lp) repl, optE (N,) repl)
-    -> rho rows (chunk, N) sharded.  No collectives inside."""
-    axes = _flat(mesh)
-
-    def local(lib_rows, ts_fut, optE):
-        return ccm.ccm_block(lib_rows, ts_fut, optE, cfg)
-
-    return jax.jit(
-        shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(P(axes, None), P(None, None), P(None)),
-            out_specs=P(axes, None),
-            check_vma=False,
-        )
-    )
-
-
 def make_ccm_chunk_fn_bucketed(mesh, cfg: EDMConfig, plan: "ccm.BucketPlan"):
     """Bucketed variant: (lib_rows sharded, ts_fut_sorted repl) -> rho rows
     (chunk, N) sharded, columns in plan-sorted target order."""
@@ -120,23 +101,6 @@ def make_ccm_chunk_fn_bucketed(mesh, cfg: EDMConfig, plan: "ccm.BucketPlan"):
 
 
 # --------------------------------------------- tiled phase 2 (DESIGN.md SS7)
-def make_ccm_tables_fn(mesh, cfg: EDMConfig):
-    """(chunk, L) sharded -> (idx, w) tables sharded on rows, all-E layout.
-    Called once per row chunk; the tables stay on device and feed every
-    column tile of that chunk."""
-    axes = _flat(mesh)
-    tspec = P(axes, None, None, None)
-    return jax.jit(
-        shard_map(
-            lambda rows: ccm._block_tables(rows, cfg),
-            mesh=mesh,
-            in_specs=(P(axes, None),),
-            out_specs=(tspec, tspec),
-            check_vma=False,
-        )
-    )
-
-
 def make_ccm_tables_fn_bucketed(mesh, cfg: EDMConfig, plan: "ccm.BucketPlan"):
     """Bucketed tables variant: (chunk, L) sharded -> (idx, w) sharded."""
     axes = _flat(mesh)
@@ -147,27 +111,6 @@ def make_ccm_tables_fn_bucketed(mesh, cfg: EDMConfig, plan: "ccm.BucketPlan"):
             mesh=mesh,
             in_specs=(P(axes, None),),
             out_specs=(tspec, tspec),
-            check_vma=False,
-        )
-    )
-
-
-def make_ccm_tile_fn(mesh, cfg: EDMConfig):
-    """(idx, w sharded; fut_tile (t, Lp) repl; e_idx (t,) repl) -> rho
-    (chunk, t) sharded.  Only the LIVE tile is replicated — O(tile x Lp)
-    per device instead of the old O(N x Lp) ts_fut replication."""
-    axes = _flat(mesh)
-    tspec = P(axes, None, None, None)
-
-    def local(idx, w, fut_tile, e_idx):
-        return ccm._block_tile(idx, w, fut_tile, e_idx, cfg)
-
-    return jax.jit(
-        shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(tspec, tspec, P(None, None), P(None)),
-            out_specs=P(axes, None),
             check_vma=False,
         )
     )
@@ -359,28 +302,21 @@ def _phase2_untiled(
     ts, ts_fut, optE, cfg, mesh, chunk, chunk_plan, writer, rho, progress,
     unit, on_chunk=None,
 ):
-    """Legacy single-tile phase 2: full-width (chunk, N) row blocks."""
+    """Single-tile phase 2: full-width (chunk, N) row blocks, their
+    columns un-sorted from bucket order on the host."""
     N = ts.shape[0]
     t0 = _perf()
-    if cfg.bucketed:
-        plan, order = ccm.make_bucket_plan(optE)
-        inv = np.argsort(order)
-        chunk_fn = make_ccm_chunk_fn_bucketed(mesh, cfg, plan)
-        ts_fut_j = jnp.asarray(ts_fut[order])
-        args = (ts_fut_j,)
-    else:
-        inv = None
-        chunk_fn = make_ccm_chunk_fn(mesh, cfg)
-        ts_fut_j = jnp.asarray(ts_fut)
-        args = (ts_fut_j, jnp.asarray(optE))
+    plan, order = ccm.make_bucket_plan(optE)
+    inv = np.argsort(order)
+    chunk_fn = make_ccm_chunk_fn_bucketed(mesh, cfg, plan)
+    ts_fut_j = jnp.asarray(ts_fut[order])
     unit["prep_s"] = _perf() - t0
     unit["futures_bytes"] = int(ts_fut_j.nbytes)
 
     def drain(tag, rho_rows):
         row0, valid = tag
-        if inv is not None:
-            with telemetry.span("phase2", "unsort", row0=row0):
-                rho_rows = rho_rows[:, inv]
+        with telemetry.span("phase2", "unsort", row0=row0):
+            rho_rows = rho_rows[:, inv]
         rows_np = rho_rows[:valid]
         if writer is not None:
             writer.write_block(row0, rows_np)
@@ -398,7 +334,7 @@ def _phase2_untiled(
                                 rows=valid, tiled=False) as t:
                 with telemetry.span("phase2", "device_put", row0=row0):
                     rows = jnp.asarray(_pad_rows(ts[row0 : row0 + chunk], chunk))
-                dev = _dispatch(unit, row0, chunk_fn, rows, *args)
+                dev = _dispatch(unit, row0, chunk_fn, rows, ts_fut_j)
                 t["chunk_rows"] = chunk
             unit["chunks"] += 1
             unit["rows"] += valid
@@ -415,21 +351,12 @@ def _phase2_tiled(
     N = ts.shape[0]
     T = cfg.target_tile
     t0 = _perf()
-    if cfg.bucketed:
-        plan, order = ccm.make_bucket_plan(optE)
-        tables_fn = make_ccm_tables_fn_bucketed(mesh, cfg, plan)
-        tile_fn_for = make_ccm_tile_fn_bucketed(mesh, cfg)
-        tile_plans = ccm.make_tile_plans(plan, T)
-        if writer is not None:
-            writer.ensure_col_order(order)
-    else:
-        order = None
-        tables_fn = make_ccm_tables_fn(mesh, cfg)
-        tile_fn = make_ccm_tile_fn(mesh, cfg)
-        tile_plans = [(c0, None) for c0 in range(0, N, T)]
-        e_idx_host = optE.astype(np.int32) - 1
-        if writer is not None:
-            writer.ensure_col_order(None)
+    plan, order = ccm.make_bucket_plan(optE)
+    tables_fn = make_ccm_tables_fn_bucketed(mesh, cfg, plan)
+    tile_fn_for = make_ccm_tile_fn_bucketed(mesh, cfg)
+    tile_plans = ccm.make_tile_plans(plan, T)
+    if writer is not None:
+        writer.ensure_col_order(order)
     unit["prep_s"] = _perf() - t0
     unit["futures_bytes"] = 0  # tiles go up per chunk, counted below
 
@@ -442,10 +369,8 @@ def _phase2_tiled(
             # to once per row chunk (drains are ordered, so when the last
             # tile lands every earlier tile of the chunk is durable).
             writer.write_tile(row0, col0, blk, commit=last_tile)
-        elif order is not None:
-            rho[row0 : row0 + valid][:, order[col0 : col0 + blk.shape[1]]] = blk
         else:
-            rho[row0 : row0 + valid, col0 : col0 + blk.shape[1]] = blk
+            rho[row0 : row0 + valid][:, order[col0 : col0 + blk.shape[1]]] = blk
         if progress and last_tile:
             print(f"ccm rows {row0}..{row0 + valid} / {N} (tiles of {T})")
 
@@ -462,20 +387,12 @@ def _phase2_tiled(
                 idx, w = _dispatch(unit, row0, tables_fn, rows)  # once per chunk
                 for c0, seg_plan in tile_plans:
                     c1 = min(c0 + T, N)
-                    # per-tile slice only — a gather through `order` in the
-                    # bucketed layout, so NO second (N, Lp) sorted host copy
-                    fut_tile = jnp.asarray(
-                        ts_fut[order[c0:c1]] if order is not None else ts_fut[c0:c1]
-                    )
+                    # per-tile slice only — a gather through `order`, so
+                    # NO second (N, Lp) sorted host copy
+                    fut_tile = jnp.asarray(ts_fut[order[c0:c1]])
                     unit["futures_bytes"] += int(fut_tile.nbytes)
-                    if seg_plan is not None:
-                        block = _dispatch(unit, row0, tile_fn_for(seg_plan),
-                                          idx, w, fut_tile)
-                    else:
-                        block = _dispatch(
-                            unit, row0, tile_fn, idx, w, fut_tile,
-                            jnp.asarray(e_idx_host[c0:c1]),
-                        )
+                    block = _dispatch(unit, row0, tile_fn_for(seg_plan),
+                                      idx, w, fut_tile)
                     streamer.submit((row0, c0, valid), block)
                 t["chunk_rows"] = chunk
             unit["chunks"] += 1

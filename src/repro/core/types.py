@@ -24,10 +24,11 @@ class EDMConfig:
         tables, simplex forecast, and CCM lookup: "reference" (pure jnp),
         "pallas-interpret", "pallas-compiled", or any registered backend
         (DESIGN.md SS5).
-      bucketed: run phase-2 CCM with optE-bucketed tables — build kNN
-        tables only for the distinct optE values present and group targets
-        by bucket for contiguous lookups (DESIGN.md SS3).  Output matches
-        the all-E path; disable only for A/B benchmarks.
+      bucketed: must be True.  Phase 2 has one table layout: kNN tables
+        only for the distinct optE values present, targets grouped by
+        bucket for contiguous lookups (DESIGN.md SS3).  The all-E phase-2
+        layout was removed; its oracle is repro.core.baseline.ccm_naive.
+        The field stays because stored run fingerprints hash it.
       stream_depth: CCM row chunks in flight in the pipeline's streaming
         loop.  2 = double buffering (chunk i+1 dispatched while chunk i's
         device->host copy and row-block write drain); 1 = the fully
@@ -77,18 +78,17 @@ class EDMConfig:
     #   rebuild    — per-E matmul-form rebuild (the PAPER-FAITHFUL shape:
     #                mpEDM recomputes each E's kNN from scratch)
     #   scan       — cumulative-E lax.scan (beyond-paper; cost_analysis
-    #                cannot see scan bodies, so dry-runs avoid it)
+    #                cannot see scan bodies)
     #   unroll     — cumulative-E python loop (XLA fuses consecutive updates)
     #   blocked:g  — scan over blocks of g unrolled steps (DEFAULT; falls
     #                back to unroll when E_max %% g != 0)
     knn_impl: str = "blocked:4"
     dist_dtype: str = "float32"  # bfloat16 halves distance-tile HBM traffic
     knn_tile_c: int = 0  # 0 auto-calibrated; >0 forced streaming tile width
-    # k_override: pins the neighbour-table width independent of E_max —
-    # used by the dry-run's reduced-E cost compiles so per-E bodies carry
-    # the PRODUCTION top-k cost (k tracks E_max otherwise).  None = unset
-    # (k tracks E_max / the bucket set); 0 is rejected so "unset" can never
-    # be confused with a (meaningless) zero-neighbour table.
+    # k_override: pins the neighbour-table width independent of E_max.
+    # None = unset (k tracks E_max / the bucket set); 0 is rejected so
+    # "unset" can never be confused with a (meaningless) zero-neighbour
+    # table.
     k_override: Optional[int] = None
 
     def __post_init__(self):
@@ -112,6 +112,12 @@ class EDMConfig:
             # entries with) the equivalent engine=... config, and so
             # dataclasses.replace(cfg, engine=...) is not overridden again.
             object.__setattr__(self, "use_kernels", None)
+        if not self.bucketed:
+            raise ValueError(
+                "bucketed=False is not supported: the all-E phase-2 table "
+                "layout was removed; use repro.core.baseline.ccm_naive as "
+                "the all-E oracle"
+            )
         if self.stream_depth < 1:
             raise ValueError("stream_depth must be >= 1")
         if self.target_tile < 0:

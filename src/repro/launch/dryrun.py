@@ -11,7 +11,6 @@ collective-roofline analysis.
 Usage:
   python -m repro.launch.dryrun --arch qwen2-1.5b --cell train_4k [--multi-pod]
   python -m repro.launch.dryrun --all [--multi-pod]   # every applicable cell
-  python -m repro.launch.dryrun --edm subject11       # the EDM pipeline cell
 Results are appended to benchmarks/results/dryrun/<name>.json.
 """
 import argparse
@@ -311,19 +310,9 @@ def main():
     ap.add_argument("--cell", choices=[c.name for c in SHAPE_CELLS])
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--edm", choices=["fish1_normo", "subject6", "subject11"])
     ap.add_argument("--optimized", action="store_true",
                     help="beyond-paper optimized configs (SSPerf)")
     args = ap.parse_args()
-
-    if args.edm:
-        from repro.launch.edm_dryrun import lower_edm_cell
-
-        res = lower_edm_cell(args.edm, multi_pod=args.multi_pod)
-        path = save_result(res)
-        print(json.dumps(res, indent=2))
-        print(f"saved -> {path}")
-        return
 
     cells = (
         [(a, c.name) for a in ARCHS for c in SHAPE_CELLS]

@@ -270,10 +270,6 @@ def build_parser() -> argparse.ArgumentParser:
         "is bit-identical at every width",
     )
     ap.add_argument(
-        "--no-bucketed", action="store_true",
-        help="disable optE-bucketed phase 2 (all-E tables; A/B baseline)",
-    )
-    ap.add_argument(
         "--stream-depth", type=int, default=2,
         help="CCM blocks in flight (2 = double buffering, 1 = synchronous)",
     )
@@ -390,9 +386,8 @@ def main():
         engine = "reference"
     cfg = EDMConfig(
         E_max=args.e_max, tau=args.tau, lib_block=args.lib_block,
-        engine=engine, bucketed=not args.no_bucketed,
-        stream_depth=args.stream_depth, target_tile=args.target_tile,
-        knn_tile_c=args.knn_tile,
+        engine=engine, stream_depth=args.stream_depth,
+        target_tile=args.target_tile, knn_tile_c=args.knn_tile,
     )
     if args.workers > 0:
         # The fleet driver stays off the jax backend (its workers need the

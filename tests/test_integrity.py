@@ -3,6 +3,7 @@ fingerprint stamp/verify contract, and the fsck scan/heal cycle over a
 real fleet store — truncated tile, bit-flipped tile, orphaned tile,
 stale-fingerprint resume — each detected, reported in --json, and (where
 healable) recomputed to byte-identical output by one fleet pass."""
+import dataclasses
 import json
 import pathlib
 import shutil
@@ -109,6 +110,16 @@ def test_fingerprint_pins_data_and_config(tmp_path):
     integrity.stamp_fingerprint(tmp_path, fp)  # idempotent
     with pytest.raises(integrity.IntegrityError, match="fingerprint"):
         integrity.stamp_fingerprint(tmp_path, integrity.fingerprint_of(ts + 1, CFG))
+
+
+def test_default_config_fingerprint_pinned():
+    """Stores stamped by earlier releases must stay resumable: the
+    fingerprint of a fixed default config is pinned to the value those
+    releases computed, so removing or renaming an EDMConfig field needs a
+    canonicalisation rule in run_fingerprint."""
+    fp = integrity.run_fingerprint(
+        "0000abcd", (16, 300), "float32", dataclasses.asdict(EDMConfig()))
+    assert fp == "caeed476c1de442c"
 
 
 # ------------------------------------------------------ fleet store fsck

@@ -1,13 +1,15 @@
 """Target-tiled phase-2 CCM (DESIGN.md SS7) + the PR bugfix sweep:
 
   * tiled vs untiled rho bit-identical across tile sizes (including ones
-    that don't divide N) and both bucketed/all-E table layouts;
+    that don't divide N), and tiled rho against the all-E oracle
+    ``ccm_naive`` at every tile size;
   * no dense (N, N) host allocation when phase 2 streams to a store;
   * TileWriter 2D manifest: coverage, elastic/fragmented chunk_plan,
     col_order persistence, assemble (dense and memmap);
   * simplex_weights tied-neighbour (d1 ~ 0) handling — dead-neuron
     datasets produce a finite causal map end-to-end;
-  * k_override / k <= Lp validation.
+  * k_override / k <= Lp validation, and the removed all-E layout
+    refused by EDMConfig.
 """
 import numpy as np
 import pytest
@@ -18,7 +20,7 @@ from repro.core import (
     BucketPlan,
     EDMConfig,
     ccm_matrix,
-    ccm_row_tables,
+    ccm_naive,
     ccm_row_tables_bucketed,
     make_bucket_plan,
     make_tile_plans,
@@ -30,30 +32,40 @@ from repro.data.synthetic import dummy_brain
 
 
 # ------------------------------------------------------- tiled bit-identity
-@pytest.mark.parametrize("bucketed", [True, False])
-def test_tiled_matrix_bit_identical_across_tile_sizes(bucketed):
+def test_tiled_matrix_bit_identical_across_tile_sizes():
     """Acceptance: tiling the target axis must not change a single bit,
-    for dividing and non-dividing tile widths, in both table layouts."""
+    for dividing and non-dividing tile widths."""
     N = 14
     ts = jnp.asarray(dummy_brain(N, 250, seed=21))
-    cfg0 = EDMConfig(E_max=5, bucketed=bucketed)
+    cfg0 = EDMConfig(E_max=5)
     _, optE = simplex_batch(ts, cfg0)
     base = np.asarray(ccm_matrix(ts, optE, cfg0))
     for tile in (3, 5, N, 4 * N):  # 5 and 3 do not divide N=14
-        cfg = EDMConfig(E_max=5, bucketed=bucketed, target_tile=tile)
+        cfg = EDMConfig(E_max=5, target_tile=tile)
         tiled = np.asarray(ccm_matrix(ts, optE, cfg))
         np.testing.assert_array_equal(tiled, base, err_msg=f"tile={tile}")
 
 
-@pytest.mark.parametrize("bucketed", [True, False])
-def test_tiled_pipeline_bit_identical_with_store(tmp_path, bucketed):
+@pytest.mark.parametrize("tile", [3, 5, 14, 56])  # N=14: 3 and 5 do not divide
+def test_tiled_matrix_matches_naive_oracle(tile):
+    """The tiled bucketed map agrees with the all-E oracle (paper Alg. 1,
+    one table per pair) at every tile width."""
+    ts = jnp.asarray(dummy_brain(14, 250, seed=21))
+    cfg = EDMConfig(E_max=5, target_tile=tile)
+    _, optE = simplex_batch(ts, cfg)
+    tiled = np.asarray(ccm_matrix(ts, optE, cfg))
+    naive = np.asarray(ccm_naive(ts, optE, cfg))
+    np.testing.assert_allclose(tiled, naive, rtol=0, atol=1e-5)
+
+
+def test_tiled_pipeline_bit_identical_with_store(tmp_path):
     """Full pipeline: tiled + streamed-to-store == untiled in-memory."""
     from repro.core.pipeline import run_causal_inference
 
     ts = dummy_brain(13, 230, seed=3)
-    base = run_causal_inference(ts, EDMConfig(E_max=4, lib_block=3, bucketed=bucketed))
-    cfg = EDMConfig(E_max=4, lib_block=3, bucketed=bucketed, target_tile=5)
-    out = run_causal_inference(ts, cfg, out_dir=str(tmp_path / f"b{bucketed}"))
+    base = run_causal_inference(ts, EDMConfig(E_max=4, lib_block=3))
+    cfg = EDMConfig(E_max=4, lib_block=3, target_tile=5)
+    out = run_causal_inference(ts, cfg, out_dir=str(tmp_path / "tiled"))
     assert isinstance(out.rho, np.memmap)  # disk-backed, not a host array
     np.testing.assert_array_equal(np.asarray(out.rho), np.asarray(base.rho))
 
@@ -210,18 +222,26 @@ def test_k_override_zero_rejected():
     assert EDMConfig(E_max=7).k_max == 8  # None -> tracks E_max
 
 
+def test_all_E_layout_refused_and_oracle_named():
+    """Phase 2 has one table layout; bucketed=False names the oracle."""
+    with pytest.raises(ValueError, match="ccm_naive"):
+        EDMConfig(bucketed=False)
+    assert EDMConfig().bucketed
+
+
 def test_k_exceeding_library_points_raises_clear_error():
     """Short series with large optE/k must fail with a diagnosable error,
     not crash inside lax.top_k."""
     x = jnp.asarray(np.linspace(0, 1, 16), jnp.float32)
     with pytest.raises(ValueError, match="library points"):
-        ccm_row_tables(x, EDMConfig(E_max=8))  # Lp=8 < k_max=9
-    with pytest.raises(ValueError, match="library points"):
-        ccm_row_tables_bucketed(
+        ccm_row_tables_bucketed(  # Lp=8 < k=9
             x, EDMConfig(E_max=8), BucketPlan(buckets=(8,), counts=(1,))
         )
     with pytest.raises(ValueError, match="library points"):
-        ccm_row_tables(x, EDMConfig(E_max=3, k_override=500))
+        ccm_row_tables_bucketed(
+            x, EDMConfig(E_max=3, k_override=500),
+            BucketPlan(buckets=(2,), counts=(1,)),
+        )
     # k_override=1 is explicit and honoured (the old `or` idiom could not
     # distinguish unset from small-but-set)
     idx, w = ccm_row_tables_bucketed(

@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-FISH1_N, FISH1_L = 53_053, 1_450  # configs/edm_datasets.py
+FISH1_N, FISH1_L = 53_053, 1_450  # benchmarks/chip/configs/fish1_normo.json
 LP_FISH1, LP_SUBJECT11 = 1_430, 8_508  # L - (E_max - 1) - Tp at E_max 20
 E_MAX, K = 20, 21
 HBM_BYTES = 16 * 10**9  # one v5e chip
